@@ -72,12 +72,12 @@ def _bench_backends(rows, smoke: bool):
         f"gain={dt_old / dt_new:.2f}x (>1 means the lowering-free 1x1 "
         f"GEMM beats forcing the im2col window copy)",
     ))
-    # pallas runs in interpret mode on CPU (Python): tiny shape, parity
+    # pallas in interpret mode (Python on the CPU): tiny shape, parity
     # timing only — kernel perf is only meaningful on a real TPU
     xt = x[:1, :8, :8, :2].copy()
     wt = w[:, :, :2, :8].copy()
     gt = g[:1, :8, :8, :8].copy()
-    bk = get_backend("pallas")
+    bk = get_backend("pallas:interpret")
     dt = _time(bk.conv, xt, wt)
     dtv = _time(lambda *a: bk.conv_vjp(*a), xt, wt, gt)
     rows.append((

@@ -1,12 +1,8 @@
-"""Version-compat helpers for the pinned jax.
+"""Mesh helpers over the installed jax (0.9) API.
 
-The global-mesh context manager has been renamed twice across jax
-releases: ``jax.set_mesh`` (0.6+), ``jax.sharding.use_mesh`` (0.5.x),
-and before that ``Mesh`` itself was the context manager.  ``shard_map``
-moved from ``jax.experimental.shard_map`` (with ``check_rep``) to
-``jax.shard_map`` (with ``check_vma``).  Every caller goes through this
-module so the repo runs unmodified on whichever API the installed jax
-exposes.
+Every caller goes through this module, so a later jax rename is one
+edit: ``jax.set_mesh`` activates a mesh, ``jax.sharding.get_abstract_mesh``
+reads it back, and ``jax.shard_map`` maps over it.
 """
 from __future__ import annotations
 
@@ -14,53 +10,23 @@ import jax
 
 
 def mesh_context(mesh):
-    """Return a context manager that activates ``mesh`` for the enclosed
-    region, across jax versions:
-
-        jax.set_mesh(mesh)            # jax >= 0.6
-        jax.sharding.use_mesh(mesh)   # jax 0.5.x
-        with mesh: ...                # jax <= 0.4.x (Mesh.__enter__)
-
-    Usage: ``with mesh_context(mesh): ...``
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh  # Mesh is itself a context manager on the legacy API
+    """Context manager that activates ``mesh`` for the enclosed region:
+    ``with mesh_context(mesh): ...``."""
+    return jax.set_mesh(mesh)
 
 
 def get_active_mesh():
-    """The mesh activated by :func:`mesh_context` for the current thread,
-    or ``None``.  Uses ``jax.sharding.get_abstract_mesh`` where it exists;
-    the legacy fallback reads the thread-local physical mesh that
-    ``Mesh.__enter__`` installs.  Either way the result has ``axis_names``
-    and ``axis_sizes``."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        mesh = getter()
-    else:
-        from jax._src import mesh as _mesh_lib
-
-        mesh = _mesh_lib.thread_resources.env.physical_mesh
-    if mesh is None or getattr(mesh, "empty", True):
+    """The mesh activated by :func:`mesh_context`, or ``None``.  The
+    result has ``axis_names`` and ``axis_sizes``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or mesh.empty:
         return None
     return mesh
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` (0.6+, ``check_vma``) falling back to
-    ``jax.experimental.shard_map.shard_map`` (``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """``jax.shard_map`` with the repo's keyword spelling."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )

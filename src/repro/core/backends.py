@@ -17,7 +17,9 @@ different kernels (the paper's CPU/GPU scenario):
     xla     — ``jax.lax.conv_general_dilated`` jitted per shape (jit's
               own cache keys on shapes/dtypes).
     pallas  — the MXU direct-conv kernel (kernels/conv2d.py) forward and
-              the Pallas dX/dW backward; interpret mode off-TPU.
+              the Pallas dX/dW backward, compiled for the TPU; off a TPU
+              it raises unless interpret mode is asked for by name
+              (``"pallas:interpret"``).
 
 All primitives take and return **numpy** arrays: the master/slave
 protocol moves serialized host buffers (the emulated sockets), and numpy
@@ -28,7 +30,7 @@ computed from probe times are exact per backend.
 from __future__ import annotations
 
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
@@ -276,25 +278,52 @@ class XlaBackend(ConvBackend):
 # ---------------------------------------------------------------------------
 
 
+def require_tpu(what: str) -> None:
+    """Raise unless JAX's default device is a TPU: compiled Pallas kernels
+    run nowhere else, and silently interpreting them instead would hide a
+    lost chip behind a run orders of magnitude slower."""
+    import jax
+
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        raise RuntimeError(
+            f"{what} runs Pallas kernels compiled for a TPU, but JAX's "
+            f"default device is {platform!r}; ask for interpret mode by "
+            f"name ('pallas:interpret', or interpret=True) to run them "
+            f"slowly on this platform"
+        )
+
+
+def backend_platform(name: str) -> str:
+    """The platform backend ``name`` computes on in this process: ``cpu``
+    for the host-only numpy and sim backends (no jax import), else JAX's
+    default device platform."""
+    if name.partition(":")[0] in ("numpy", "sim"):
+        return "cpu"
+    import jax
+
+    return jax.devices()[0].platform
+
+
 @register_backend("pallas")
 class PallasBackend(ConvBackend):
-    """Runs kernels/conv2d.py.  Off-TPU the kernels execute in Pallas
-    interpret mode — bit-accurate but slow, meant for CI parity tests."""
+    """Runs kernels/conv2d.py compiled for the TPU.  ``"pallas:interpret"``
+    (``interpret=True``) runs the same kernels in Pallas interpret mode
+    on any platform — bit-accurate but slow, meant for CPU parity tests;
+    plain ``"pallas"`` off a TPU raises instead."""
 
     name = "pallas"
 
-    def __init__(self, interpret=None):
-        import jax
-
+    def __init__(self, interpret=False):
         if isinstance(interpret, str):  # registry parameter, e.g. "pallas:interpret"
             if interpret not in ("interpret", "compiled"):
                 raise ValueError(
                     f"pallas parameter must be 'interpret' or 'compiled', got {interpret!r}"
                 )
             interpret = interpret == "interpret"
-        if interpret is None:
-            interpret = jax.devices()[0].platform != "tpu"
         self.interpret = bool(interpret)
+        if not self.interpret:
+            require_tpu("the 'pallas' backend")
 
     def conv(self, x, w):
         import jax.numpy as jnp
@@ -404,11 +433,15 @@ def probe_conv_time(
 # ---------------------------------------------------------------------------
 
 
-def make_conv_fn(name: str, *, interpret: Optional[bool] = None):
+def make_conv_fn(name: str, *, interpret: bool = False):
     """Return a ``conv_fn(params, x)`` for ``cnn_forward`` that computes
-    the convolution with the named backend, differentiable end to end."""
+    the convolution with the named backend, differentiable end to end.
+    ``pallas`` needs a TPU unless ``interpret=True`` (or the registry
+    spelling ``"pallas:interpret"``)."""
     import jax
 
+    if name == "pallas:interpret":
+        name, interpret = "pallas", True
     if name == "xla":
         from repro.layers.conv import apply_conv
 
@@ -421,9 +454,9 @@ def make_conv_fn(name: str, *, interpret: Optional[bool] = None):
             conv2d_pallas,
         )
 
-        interp = (
-            jax.devices()[0].platform != "tpu" if interpret is None else bool(interpret)
-        )
+        interp = bool(interpret)
+        if not interp:
+            require_tpu("make_conv_fn('pallas')")
 
         @jax.custom_vjp
         def pconv(x, w):

@@ -244,6 +244,9 @@ class HeteroCluster:
         # here, not kill a slave later and leave the master blocked
         # forever.  (External joiners' backends run on THEIR host and
         # are recorded as-is.)
+        if transport in ("tcp", "shm") and expected_slaves is None:
+            for name in self.backends[1:]:
+                check_spawnable_backend(name)
         for name in self.backends:
             get_backend(name)
         self._master_backend = get_backend(self.backends[0])
@@ -459,10 +462,14 @@ class HeteroCluster:
         ]
 
     def _slave_env(self) -> dict:
-        """Environment for a spawned slave process: the src/ import root
-        and the per-cluster auth secret (env, not argv — argv shows in
-        ps)."""
+        """Environment for a spawned slave process: the src/ import root,
+        the per-cluster auth secret (env, not argv — argv shows in ps),
+        and ``JAX_PLATFORMS=cpu``.  A chip belongs to one process, and
+        this one may already hold it (its own members and master-only
+        jits run there), so a spawned slave is always a host-CPU member;
+        its hello reports the platform it runs on."""
         env = os.environ.copy()
+        env["JAX_PLATFORMS"] = "cpu"
         src = _src_pythonpath()
         env["PYTHONPATH"] = src + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
@@ -697,6 +704,7 @@ class HeteroCluster:
         else:
             dev_hint = None
             if spawn:
+                check_spawnable_backend(backend)
                 get_backend(backend)
                 dev_hint = self._next_slave_id
                 self._next_slave_id += 1
@@ -1520,6 +1528,21 @@ class HeteroCluster:
             self._listener.close()
 
 
+def check_spawnable_backend(name: str) -> None:
+    """Refuse a compiled ``pallas`` member for a spawned slave process:
+    spawned slaves run on the host CPU (see ``_slave_env``), where Pallas
+    kernels could only be interpreted.  ``"pallas:interpret"`` asks for
+    that by name and passes."""
+    base, _, param = name.partition(":")
+    if base == "pallas" and param != "interpret":
+        raise ValueError(
+            f"a spawned slave runs on the host CPU, so backend {name!r} "
+            f"could only run its Pallas kernels interpreted; use 'numpy' "
+            f"or 'xla' there, 'pallas:interpret' to interpret on purpose, "
+            f"or an inproc member for the chip"
+        )
+
+
 def make_distributed_conv(cluster: HeteroCluster):
     """A drop-in ``conv_fn`` for models/cnn.py: jax custom-VJP convolution
     whose forward and backward run over the cluster via callbacks.  If the
@@ -1532,10 +1555,12 @@ def make_distributed_conv(cluster: HeteroCluster):
     # Fail fast on the documented deadlock instead of hanging at 0% CPU:
     # the callbacks below block the jax runtime thread while the master
     # computes its shard, so any master backend that re-enters jit
-    # dispatch — everything but numpy — deadlocks, as does a pallas slave
-    # in interpret mode (interpret re-enters jax from the slave thread
-    # against the blocked callback; subprocess TCP slaves dodge this by
-    # construction, but inproc slave threads share the runtime).
+    # dispatch — everything but numpy — deadlocks, as does a
+    # "pallas:interpret" slave (interpret re-enters jax from the slave
+    # thread against the blocked callback; subprocess TCP slaves dodge
+    # this by construction, but inproc slave threads share the runtime).
+    # Plain "pallas" is compiled TPU code and cannot reach this branch
+    # off a TPU: the backend refuses to construct there.
     if cluster.backends[0] != "numpy":
         raise RuntimeError(
             f"make_distributed_conv drives the cluster through jax host "

@@ -49,7 +49,7 @@ import time
 from typing import Callable, Dict, List, Optional, Sequence, Union
 
 from repro.core.cluster import plans, protocol
-from repro.core.cluster.cluster import HeteroCluster
+from repro.core.cluster.cluster import HeteroCluster, check_spawnable_backend
 from repro.core.cluster.transport import InProcTransport
 
 
@@ -206,6 +206,11 @@ class HierarchicalCluster(HeteroCluster):
         groups = list(groups)
         if not groups:
             raise ValueError("a hierarchy needs at least one group")
+        if transport in ("tcp", "shm"):
+            # every group member lives in its spawned sub-master process
+            for g in groups:
+                for name in g.backends or ():
+                    check_spawnable_backend(name)
         # state the base __init__'s member startup (which we override)
         # consumes — must exist before super().__init__ runs
         self._pending_specs: "collections.deque[GroupSpec]" = (

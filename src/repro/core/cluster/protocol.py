@@ -45,9 +45,9 @@ master's listener:
 It connects back to the master's listener (retrying while the master is
 still binding), presents the cluster auth token (read from the env var
 named by ``--auth-env``), identifies itself with a
-``("hello", device, {"backend", "slowdown"})`` frame, and waits for the
-master's ``("welcome", assigned_device)`` — the master owns device
-numbering, so a hand-launched slave may omit ``--device`` entirely and
+``("hello", device, {"backend", "slowdown", "platform"})`` frame, and
+waits for the master's ``("welcome", assigned_device)`` — the master
+owns device numbering, so a hand-launched slave may omit ``--device`` entirely and
 take whatever slot the cluster assigns.  With ``--heartbeat-s`` it
 beats liveness frames from a side thread so a master with a heartbeat
 deadline can tell "busy convolving" from "dead".  It then serves ops
@@ -388,7 +388,9 @@ def main(argv=None):
     code = 0
     inner = None
     try:
-        extra = None
+        from repro.core.backends import backend_platform
+
+        extra = {"platform": backend_platform(args.backend)}
         if args.group_slowdowns:
             # Lazy on purpose: hierarchy -> cluster pulls the full
             # master-side stack; plain leaf slaves must stay jax-free
@@ -413,7 +415,7 @@ def main(argv=None):
                 bandwidth_mbps=args.group_bandwidth_mbps,
                 nic_mbps=args.group_nic_mbps,
             ))
-            extra = {"group": group_hello_meta(inner)}
+            extra["group"] = group_hello_meta(inner)
         endpoint.send(
             hello_frame(args.device, args.backend, args.slowdown, extra)
         )

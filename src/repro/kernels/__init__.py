@@ -1,3 +1,3 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels (``conv2d``, ``flash_attn``, ``ssd``) and their
+pure-jnp oracles (``ref``).  Callers pass ``interpret=True`` to run a
+kernel off a TPU; nothing here picks interpret mode on its own."""

@@ -15,44 +15,19 @@ import jax.numpy as jnp  # noqa: E402
 from repro.compat import mesh_context  # noqa: E402
 from repro.configs.base import InputShape  # noqa: E402
 from repro.configs.cifar_cnn import CONFIGS  # noqa: E402
-from repro.core.conv_shard import make_sharded_conv  # noqa: E402
+from repro.core.conv_shard import make_sharded_train_step  # noqa: E402
 from repro.launch.mesh import make_production_mesh, mesh_name  # noqa: E402
-from repro.models.cnn import cnn_axes, cnn_loss, init_cnn  # noqa: E402
+from repro.models.cnn import init_cnn  # noqa: E402
 from repro.models.registry import rules_for_mode  # noqa: E402
 from repro.roofline.analysis import RooflineReport  # noqa: E402
 from repro.roofline.hlo_parse import analyze_hlo  # noqa: E402
-from repro.sharding.partitioning import param_sharding_for_tree, spec_for_shape  # noqa: E402
 
 
 def dryrun_cnn(arch: str, batch: int, tp_mode: str, multi_pod: bool = False):
     cfg = CONFIGS[arch]
     mesh = make_production_mesh(multi_pod=multi_pod)
-    rules = rules_for_mode(tp_mode)
-    conv_fn = make_sharded_conv(rules)
-
+    jitted, _ = make_sharded_train_step(cfg, mesh, rules_for_mode(tp_mode), batch)
     abstract = jax.eval_shape(lambda: init_cnn(jax.random.key(0), cfg))
-    param_sh = param_sharding_for_tree(mesh, cnn_axes(), rules, abstract)
-    sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))
-    img_sh = jax.NamedSharding(
-        mesh, spec_for_shape(rules, (batch, 32, 32, 3), ("batch", None, None, None), sizes)
-    )
-    lbl_sh = jax.NamedSharding(
-        mesh, spec_for_shape(rules, (batch,), ("batch",), sizes)
-    )
-
-    def train_step(params, images, labels):
-        (loss, acc), grads = jax.value_and_grad(
-            lambda p: cnn_loss(p, images, labels, cfg=cfg, conv_fn=conv_fn),
-            has_aux=True,
-        )(params)
-        new = jax.tree.map(lambda p, g: p - 0.05 * g, params, grads)
-        return new, loss, acc
-
-    jitted = jax.jit(
-        train_step,
-        in_shardings=(param_sh, img_sh, lbl_sh),
-        out_shardings=(param_sh, None, None),
-    )
     with mesh_context(mesh):
         lowered = jitted.lower(
             abstract,

@@ -102,6 +102,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core.master_slave import HeteroCluster, make_distributed_conv
 from repro.core.partitioner import workload_shares
 from repro.models.cnn import (
@@ -110,6 +111,17 @@ from repro.models.cnn import (
     make_cluster_train_step,
     make_cnn_config,
 )
+
+
+def train_inputs(cfg, batch: int):
+    """The seeded initial parameters, images and labels every training
+    run starts from: ``(params, images, labels)``."""
+    params = init_cnn(jax.random.key(0), cfg)
+    imgs = jax.random.normal(
+        jax.random.key(1), (batch, cfg.image_size, cfg.image_size, cfg.image_channels)
+    )
+    labels = jnp.arange(batch) % cfg.num_classes
+    return params, imgs, labels
 
 
 def run_hetero(
@@ -137,7 +149,15 @@ def run_hetero(
     groups=None,
     group_partition: str = "auto",
     master_nic_mbps=None,
+    on_step=None,
 ) -> dict:
+    """Train ``steps`` SGD steps of the CNN over a cluster built from
+    ``slowdowns``/``backends`` and return the run's record: probe times,
+    per-step losses, set-up seconds (cluster start-up and Eq. 1 probe)
+    apart from each step's seconds (the first includes compiling every
+    shard shape), and the timing breakdown.  ``on_step(i, params,
+    loss)``, if given, sees the parameters after each step."""
+    t_start = time.perf_counter()
     if not train_pipeline and backends is not None and backends[0] != "numpy":
         # the callback training loop re-enters jax on the blocked runtime
         # thread with a non-numpy master and can deadlock — fail fast
@@ -208,9 +228,7 @@ def run_hetero(
         print(f"Eq.1 shares: {np.round(shares, 3).tolist()} -> "
               f"c2 kernels {cluster.shares_for(c2).tolist()}")
 
-        params = init_cnn(jax.random.key(0), cfg)
-        imgs = jax.random.normal(jax.random.key(1), (batch, 32, 32, 3))
-        labels = jnp.arange(batch) % cfg.num_classes
+        params, imgs, labels = train_inputs(cfg, batch)
 
         if train_pipeline:
             # full-step pipeline: fwd + bwd distributed, direct driver
@@ -231,12 +249,17 @@ def run_hetero(
                 return jax.tree.map(lambda a, g: a - lr * g, p, grads), loss
 
         cluster.reset_stats()
-        t0 = time.perf_counter()
-        losses = []
-        for _ in range(steps):
+        setup_s = time.perf_counter() - t_start
+        losses, step_s = [], []
+        for i in range(steps):
+            ts = time.perf_counter()
             params, loss = train_step(params)
             losses.append(float(loss))
-        wall = time.perf_counter() - t0
+            jax.block_until_ready(params)
+            step_s.append(time.perf_counter() - ts)
+            if on_step is not None:
+                on_step(i, params, losses[-1])
+        wall = sum(step_s)
 
         t = cluster.timing
         rec = {
@@ -265,6 +288,8 @@ def run_hetero(
             "backends": list(cluster.backends),
             "probe_s": [float(x) for x in probe],
             "losses": losses,
+            "setup_s": setup_s,
+            "step_s": step_s,
             "wall_s": wall,
             "comm_mb": cluster.comm_bytes / 2 ** 20,
             "timing": dataclasses.asdict(t),
@@ -344,8 +369,9 @@ def run_serve(
         heartbeat_s=heartbeat_s,
     )
     try:
-        cluster.probe(image_size=image_size, in_channels=3, kernel_size=k,
-                      num_kernels=max(8, c1), batch=max_batch)
+        probe = cluster.probe(image_size=image_size, in_channels=3,
+                              kernel_size=k, num_kernels=max(8, c1),
+                              batch=max_batch)
         print(f"serving: slowdowns={list(cluster.slowdowns)} "
               f"backends={cluster.backends} transport={transport} "
               f"max_batch={max_batch} deadline_s={deadline_s}")
@@ -376,6 +402,7 @@ def run_serve(
             "requests": requests,
             "max_batch": max_batch,
             "deadline_s": deadline_s,
+            "probe_s": [float(x) for x in probe],
             "statuses": statuses,
             "all_ok": all_ok,
             "retries": sum(r.retries for r in resps),
@@ -515,6 +542,7 @@ def main():
     ap.add_argument("--steps", type=int, default=2)
     ap.add_argument("--out", default=None, help="append the record as JSONL")
     args = ap.parse_args()
+    configure_compile_cache()
 
     # the flat default topology makes no sense under --groups: there the
     # default is "just the root", group devices filling in at 1.0

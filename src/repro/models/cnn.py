@@ -58,11 +58,12 @@ def cnn_axes():
     }
 
 
-def conv_fn_for_backend(backend: str = "xla", *, interpret=None):
+def conv_fn_for_backend(backend: str = "xla", *, interpret: bool = False):
     """Return a ``conv_fn`` for ``cnn_forward`` that computes the
     convolutions with the named compute backend (core/backends.py):
     ``xla`` (lax conv, the default reference), ``pallas`` (the MXU
-    kernels forward + Pallas dX/dW backward), or ``numpy`` (im2col via
+    kernels forward + Pallas dX/dW backward; a TPU, or
+    ``interpret=True``), or ``numpy`` (im2col via
     host callback).  The distributed variants stay separate:
     core/conv_shard.py (mesh) and core/master_slave.py (cluster)."""
     from repro.core.backends import make_conv_fn

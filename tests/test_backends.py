@@ -17,7 +17,9 @@ from repro.core.backends import (
 from repro.core.master_slave import HeteroCluster, make_distributed_conv
 from repro.models.cnn import cnn_loss, init_cnn, make_cnn_config
 
-PARITY_BACKENDS = ["numpy", "xla", "pallas"]
+# interpret mode by name (plain "pallas" needs a TPU); the id keeps the
+# test names the backend's
+PARITY_BACKENDS = ["numpy", "xla", pytest.param("pallas:interpret", id="pallas")]
 
 
 def _ref_conv(x, w):
@@ -38,6 +40,23 @@ def test_registry_exposes_the_contract():
     assert {"numpy", "xla", "pallas", "sim"} <= set(available_backends())
     with pytest.raises(KeyError):
         get_backend("no-such-backend")
+
+
+def test_plain_pallas_refuses_to_interpret_silently():
+    """Off a TPU, plain "pallas" raises instead of falling back to
+    interpret mode; interpret mode runs only when asked for by name."""
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("plain pallas is the compiled kernel on a TPU")
+    with pytest.raises(RuntimeError, match="pallas:interpret"):
+        get_backend("pallas")
+    with pytest.raises(RuntimeError, match="pallas:interpret"):
+        get_backend("pallas:compiled")
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        make_conv_fn("pallas")
+    with pytest.raises(RuntimeError, match="pallas:interpret"):
+        HeteroCluster([1.0, 1.0], ["numpy", "pallas"])
+    assert get_backend("pallas:interpret").interpret
+    make_conv_fn("pallas", interpret=True)
 
 
 def test_registry_parameterized_instances():
@@ -95,7 +114,7 @@ def test_even_kernel_backends_self_consistent():
     """Even kernels: numpy and pallas share the repo's k//2-low SAME pad
     (XLA's differs), so they must agree with each other."""
     x, w, g = _data(cout=6, k=4, seed=2)
-    np_b, pl_b = get_backend("numpy"), get_backend("pallas")
+    np_b, pl_b = get_backend("numpy"), get_backend("pallas:interpret")
     np.testing.assert_allclose(pl_b.conv(x, w), np_b.conv(x, w), atol=1e-4)
     dx_n, dw_n = np_b.conv_vjp(x, w, g)
     dx_p, dw_p = pl_b.conv_vjp(x, w, g)
@@ -177,7 +196,7 @@ def test_make_conv_fn_grads_match_reference(name):
 def mixed_cluster():
     """Heterogeneous cluster where every device runs a DIFFERENT backend:
     numpy master (callback-safe), xla + pallas-interpret slaves."""
-    c = HeteroCluster([1.0, 1.5, 2.0], ["numpy", "xla", "pallas"])
+    c = HeteroCluster([1.0, 1.5, 2.0], ["numpy", "xla", "pallas:interpret"])
     c.probe(image_size=8, in_channels=3, kernel_size=5, num_kernels=8, batch=2)
     yield c
     c.shutdown()

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.conv2d import conv2d_pallas
+from repro.kernels.conv2d import conv2d_dw_pallas, conv2d_dx_pallas, conv2d_pallas
 from repro.kernels.flash_attn import flash_attention_pallas
 from repro.kernels.ssd import ssd_pallas
 
@@ -31,6 +31,27 @@ def test_conv2d_sweep(b, h, w, cin, cout, k, dtype):
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want), atol=ATOL[dtype], rtol=0.05
     )
+
+
+@pytest.mark.parametrize("cin,cout", [(40, 24), (24, 40)])
+def test_conv2d_fwd_dx_dw_over_contract_tiles(cin, cout):
+    """Channels wider than one tile: forward and dX sum over several
+    zero-padded contract tiles through the VMEM accumulator, dW tiles Cin
+    as well as Cout — each must still equal the lax conv and its VJP."""
+    ks = jax.random.split(jax.random.key(2), 3)
+    x = jax.random.normal(ks[0], (2, 8, 8, cin), jnp.float32)
+    wk = jax.random.normal(ks[1], (3, 3, cin, cout), jnp.float32) * 0.1
+    g = jax.random.normal(ks[2], (2, 8, 8, cout), jnp.float32)
+    y_want, pullback = jax.vjp(ref.conv2d_ref, x, wk)
+    dx_want, dw_want = pullback(g)
+    tiles = dict(contract_tile=16, interpret=True)
+    y = conv2d_pallas(x, wk, cout_tile=16, **tiles)
+    dx = conv2d_dx_pallas(g, wk, cin_tile=16, **tiles)
+    dw = conv2d_dw_pallas(x, g, 3, 3, cout_tile=16, cin_tile=16, interpret=True)
+    tol = dict(atol=ATOL[jnp.float32], rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_want), **tol)
+    np.testing.assert_allclose(np.asarray(dx), np.asarray(dx_want), **tol)
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_want), atol=1e-3, rtol=1e-4)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -400,6 +400,22 @@ def test_subprocess_train_chain_matches_single_device_vjp(partition, kind):
         c.shutdown()
 
 
+def test_spawned_slave_is_a_host_cpu_member(monkeypatch):
+    """A spawned slave never contends for the master's chip: its
+    environment pins JAX_PLATFORMS=cpu whatever the master's says, its
+    hello reports the platform it computes on, and a compiled pallas
+    slave — which could only run interpreted there — is refused."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")  # a chip host's setting
+    c = HeteroCluster([1.0, 1.0], ["numpy", "xla"], transport="tcp")
+    try:
+        assert c._slave_env()["JAX_PLATFORMS"] == "cpu"
+        assert c.hello_meta[c.slave_ids[0]]["platform"] == "cpu"
+    finally:
+        c.shutdown()
+    with pytest.raises(ValueError, match="host CPU"):
+        HeteroCluster([1.0, 1.0], ["numpy", "pallas"], transport="tcp")
+
+
 @pytest.mark.parametrize("kind", ["tcp", "shm"])
 def test_subprocess_orderly_shutdown_reaps_subprocesses(kind):
     c = HeteroCluster([1.0, 1.0, 1.0], transport=kind)
