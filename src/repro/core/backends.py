@@ -34,6 +34,8 @@ from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
+from repro.tracing import to_device, to_host
+
 
 class ConvBackend:
     """The per-device compute contract of the distributed conv engine."""
@@ -265,12 +267,16 @@ class XlaBackend(ConvBackend):
         self._conv = jax.jit(_conv)
         self._vjp = jax.jit(_vjp)
 
+    # the inputs are put on JAX's default device explicitly, as the jit
+    # call would put them, so that the upload is timed and counted
     def conv(self, x, w):
-        return np.asarray(self._conv(np.asarray(x), np.asarray(w)))
+        return to_host(self._conv(to_device(np.asarray(x)), to_device(np.asarray(w))))
 
     def conv_vjp(self, x, w, g):
-        dx, dw = self._vjp(np.asarray(x), np.asarray(w), np.asarray(g))
-        return np.asarray(dx), np.asarray(dw)
+        dx, dw = self._vjp(
+            to_device(np.asarray(x)), to_device(np.asarray(w)), to_device(np.asarray(g))
+        )
+        return to_host(dx), to_host(dw)
 
 
 # ---------------------------------------------------------------------------
@@ -326,25 +332,21 @@ class PallasBackend(ConvBackend):
             require_tpu("the 'pallas' backend")
 
     def conv(self, x, w):
-        import jax.numpy as jnp
-
         from repro.kernels.conv2d import conv2d_pallas
 
-        return np.asarray(
-            conv2d_pallas(jnp.asarray(x), jnp.asarray(w), interpret=self.interpret)
+        return to_host(
+            conv2d_pallas(to_device(x), to_device(w), interpret=self.interpret)
         )
 
     def conv_vjp(self, x, w, g):
-        import jax.numpy as jnp
-
         from repro.kernels.conv2d import conv2d_dw_pallas, conv2d_dx_pallas
 
         kh, kw = w.shape[0], w.shape[1]
-        dx = conv2d_dx_pallas(jnp.asarray(g), jnp.asarray(w), interpret=self.interpret)
+        dx = conv2d_dx_pallas(to_device(g), to_device(w), interpret=self.interpret)
         dw = conv2d_dw_pallas(
-            jnp.asarray(x), jnp.asarray(g), kh, kw, interpret=self.interpret
+            to_device(x), to_device(g), kh, kw, interpret=self.interpret
         )
-        return np.asarray(dx), np.asarray(dw)
+        return to_host(dx), to_host(dw)
 
 
 # ---------------------------------------------------------------------------

@@ -65,6 +65,7 @@ The cluster is ELASTIC: membership may change while it runs.
 """
 from __future__ import annotations
 
+import contextlib
 import hmac
 import os
 import secrets
@@ -76,6 +77,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro import tracing
 from repro.core.backends import (
     get_backend,
     probe_conv_time,
@@ -344,6 +346,7 @@ class HeteroCluster:
         self.reaped: List[subprocess.Popen] = []  # evicted/killed, waited on
         self.failures: List[dict] = []  # {"device", "t_detected", "error"}
         self.probe_times: Optional[List[float]] = None
+        self.probe_s: Optional[float] = None  # seconds of the last probe()
         self.probe_flops: Optional[float] = None  # flops of the probe workload
         self._probe_kwargs: Optional[dict] = None  # last probe() workload
         self.measured_bandwidths: List[Optional[float]] = [None] * n_cfg
@@ -439,7 +442,7 @@ class HeteroCluster:
         t = threading.Thread(
             target=protocol.slave_loop,
             args=(link.slave_endpoint(), slowdown, backend, dev),
-            daemon=True,
+            name=f"member-{dev}", daemon=True,
         )
         t.start()
         self._add_slot(dev, link, None, t)
@@ -859,7 +862,15 @@ class HeteroCluster:
         and, on the tcp transport, each link's measured round-trip
         bandwidth — the real wire feeds ``link_aware_times`` instead of
         the ``bandwidth_mbps`` knob.  A slave lost mid-probe is
-        auto-evicted and the times cover the survivors."""
+        auto-evicted and the times cover the survivors.  Its seconds
+        are kept in ``probe_s`` (span ``cluster.probe``), which
+        ``reset_stats`` leaves alone."""
+        with tracing.span("cluster.probe") as s:
+            times = self._probe(probe_kwargs)
+        self.probe_s = s.end - s.start
+        return times
+
+    def _probe(self, probe_kwargs: dict) -> List[float]:
         master_t = probe_conv_time(
             self._master_backend, slowdown=self.slowdowns[0], **probe_kwargs
         )
@@ -1083,17 +1094,15 @@ class HeteroCluster:
         if plan.mode == "batch":
             return self._scatter_conv_batch(x, plan, send_weights)
         socks = self._plan_sockets(plan)
-        t0 = time.perf_counter()
-        for pos, (sock, (lo, hi, pt, pb)) in enumerate(
-            zip(socks, plan.halos[1:]), start=1
-        ):
-            ws = self._wire_weights(sock, plan, pos, plan.w, send_weights)
-            self._write_op(sock, ("sconv", (x[:, lo:hi], ws, pt, pb)))
-        now = time.perf_counter()
-        self.timing.comm_s += now - t0
+        with tracing.span("cluster.scatter", self.timing, "comm_s") as scatter:
+            for pos, (sock, (lo, hi, pt, pb)) in enumerate(
+                zip(socks, plan.halos[1:]), start=1
+            ):
+                ws = self._wire_weights(sock, plan, pos, plan.w, send_weights)
+                self._write_op(sock, ("sconv", (x[:, lo:hi], ws, pt, pb)))
         self._seq_issued += 1
         return scheduler.Pending(
-            "conv", self._seq_issued, x, plan.w, None, now,
+            "conv", self._seq_issued, x, plan.w, None, scatter.end,
             mode="spatial", rows=plan.rows, halos=plan.halos,
             plan=plan, parts=socks,
         )
@@ -1104,17 +1113,15 @@ class HeteroCluster:
         """send_weights=False sends w=None: the slave reuses its cached
         shard, so pipelined microbatches pay the weight traffic once."""
         socks = self._plan_sockets(plan)
-        t0 = time.perf_counter()
-        for pos, (sock, shard) in enumerate(
-            zip(socks, plan.shards[1:]), start=1
-        ):
-            ws = self._wire_weights(sock, plan, pos, shard, send_weights)
-            self._write_op(sock, ("conv", (x, ws)))
-        now = time.perf_counter()
-        self.timing.comm_s += now - t0
+        with tracing.span("cluster.scatter", self.timing, "comm_s") as scatter:
+            for pos, (sock, shard) in enumerate(
+                zip(socks, plan.shards[1:]), start=1
+            ):
+                ws = self._wire_weights(sock, plan, pos, shard, send_weights)
+                self._write_op(sock, ("conv", (x, ws)))
         self._seq_issued += 1
         return scheduler.Pending(
-            "conv", self._seq_issued, x, plan.shards[0], None, now,
+            "conv", self._seq_issued, x, plan.shards[0], None, scatter.end,
             plan=plan, parts=socks,
         )
 
@@ -1130,15 +1137,13 @@ class HeteroCluster:
         gather and the lost-slave recovery path."""
         socks = self._plan_sockets(plan)
         rows = plans.batch_ranges(plan.counts, x.shape[0])
-        t0 = time.perf_counter()
-        for pos, (sock, (r0, r1)) in enumerate(zip(socks, rows[1:]), start=1):
-            ws = self._wire_weights(sock, plan, pos, plan.w, send_weights)
-            self._write_op(sock, ("conv", (x[r0:r1], ws)))
-        now = time.perf_counter()
-        self.timing.comm_s += now - t0
+        with tracing.span("cluster.scatter", self.timing, "comm_s") as scatter:
+            for pos, (sock, (r0, r1)) in enumerate(zip(socks, rows[1:]), start=1):
+                ws = self._wire_weights(sock, plan, pos, plan.w, send_weights)
+                self._write_op(sock, ("conv", (x[r0:r1], ws)))
         self._seq_issued += 1
         return scheduler.Pending(
-            "conv", self._seq_issued, x, plan.w, None, now,
+            "conv", self._seq_issued, x, plan.w, None, scatter.end,
             mode="batch", rows=rows, plan=plan, parts=socks,
         )
 
@@ -1150,33 +1155,32 @@ class HeteroCluster:
         contributes via the master's recovery compute instead of the
         wire."""
         self._check_order(p, "conv")
-        t0 = time.perf_counter()
-        if p.mode == "spatial":
-            lo, hi, pt, pb = p.halos[0]
-            my_out = self._master_compute(
-                lambda: strip_conv(self._master_backend, p.x[:, lo:hi], p.my_w, pt, pb)
-            )
-            axis = 1
-        elif p.mode == "batch":
-            r0, r1 = p.rows[0]
-            my_out = self._master_compute(
-                lambda: protocol.conv_shard(
-                    self._master_backend, p.x[r0:r1], p.my_w
+        with tracing.span("cluster.gather", self.timing, "conv_s"):
+            if p.mode == "spatial":
+                lo, hi, pt, pb = p.halos[0]
+                my_out = self._master_compute(
+                    lambda: strip_conv(self._master_backend, p.x[:, lo:hi], p.my_w, pt, pb)
                 )
-            )
-            axis = 0
-        else:
-            my_out = self._master_compute(
-                lambda: protocol.conv_shard(self._master_backend, p.x, p.my_w)
-            )
-            axis = -1
-        outs = [my_out]
-        t_wait = time.perf_counter()
-        for idx, sock in enumerate(p.parts):
-            outs.append(self._read_or_recover(sock, p, idx))
-        t1 = time.perf_counter()
-        self._account_gather(p, t0, t_wait, t1)
-        return np.concatenate(outs, axis=axis)
+                axis = 1
+            elif p.mode == "batch":
+                r0, r1 = p.rows[0]
+                my_out = self._master_compute(
+                    lambda: protocol.conv_shard(
+                        self._master_backend, p.x[r0:r1], p.my_w
+                    )
+                )
+                axis = 0
+            else:
+                my_out = self._master_compute(
+                    lambda: protocol.conv_shard(self._master_backend, p.x, p.my_w)
+                )
+                axis = -1
+            outs = [my_out]
+            with self._gather_wait(p):
+                for idx, sock in enumerate(p.parts):
+                    outs.append(self._read_or_recover(sock, p, idx))
+        with tracing.span("cluster.assemble"):
+            return np.concatenate(outs, axis=axis)
 
     def scatter_bwd(
         self, x: np.ndarray, w: np.ndarray, g: np.ndarray,
@@ -1209,20 +1213,18 @@ class HeteroCluster:
         if plan.mode == "batch":
             return self._scatter_bwd_batch(x, plan, g, send_weights)
         socks = self._plan_sockets(plan)
-        t0 = time.perf_counter()
-        for pos, (sock, (r0, r1), (lo, hi, pt, pb)) in enumerate(
-            zip(socks, plan.rows[1:], plan.halos[1:]), start=1
-        ):
-            ws = self._wire_weights(sock, plan, pos, plan.w, send_weights)
-            self._write_op(
-                sock, ("sbwd", (x[:, lo:hi], ws, g[:, r0:r1], pt, pb))
-            )
-        now = time.perf_counter()
-        self.timing.comm_s += now - t0
+        with tracing.span("cluster.scatter", self.timing, "comm_s") as scatter:
+            for pos, (sock, (r0, r1), (lo, hi, pt, pb)) in enumerate(
+                zip(socks, plan.rows[1:], plan.halos[1:]), start=1
+            ):
+                ws = self._wire_weights(sock, plan, pos, plan.w, send_weights)
+                self._write_op(
+                    sock, ("sbwd", (x[:, lo:hi], ws, g[:, r0:r1], pt, pb))
+                )
         self._seq_issued += 1
         r0, r1 = plan.rows[0]
         return scheduler.Pending(
-            "bwd", self._seq_issued, x, plan.w, g[:, r0:r1], now,
+            "bwd", self._seq_issued, x, plan.w, g[:, r0:r1], scatter.end,
             mode="spatial", rows=plan.rows, halos=plan.halos,
             plan=plan, parts=socks, g_all=g,
         )
@@ -1237,16 +1239,14 @@ class HeteroCluster:
         the gather.  Rows are re-cut to this slab like the forward."""
         socks = self._plan_sockets(plan)
         rows = plans.batch_ranges(plan.counts, x.shape[0])
-        t0 = time.perf_counter()
-        for pos, (sock, (r0, r1)) in enumerate(zip(socks, rows[1:]), start=1):
-            ws = self._wire_weights(sock, plan, pos, plan.w, send_weights)
-            self._write_op(sock, ("bwd", (x[r0:r1], ws, g[r0:r1])))
-        now = time.perf_counter()
-        self.timing.comm_s += now - t0
+        with tracing.span("cluster.scatter", self.timing, "comm_s") as scatter:
+            for pos, (sock, (r0, r1)) in enumerate(zip(socks, rows[1:]), start=1):
+                ws = self._wire_weights(sock, plan, pos, plan.w, send_weights)
+                self._write_op(sock, ("bwd", (x[r0:r1], ws, g[r0:r1])))
         self._seq_issued += 1
         r0, r1 = rows[0]
         return scheduler.Pending(
-            "bwd", self._seq_issued, x, plan.w, g[r0:r1], now,
+            "bwd", self._seq_issued, x, plan.w, g[r0:r1], scatter.end,
             mode="batch", rows=rows, plan=plan, parts=socks, g_all=g,
         )
 
@@ -1256,17 +1256,15 @@ class HeteroCluster:
     ) -> scheduler.Pending:
         socks = self._plan_sockets(plan)
         g_shards = self._split(g, plan.counts)
-        t0 = time.perf_counter()
-        for pos, (sock, shard, gs) in enumerate(
-            zip(socks, plan.shards[1:], g_shards[1:]), start=1
-        ):
-            ws = self._wire_weights(sock, plan, pos, shard, send_weights)
-            self._write_op(sock, ("bwd", (x, ws, gs)))
-        now = time.perf_counter()
-        self.timing.comm_s += now - t0
+        with tracing.span("cluster.scatter", self.timing, "comm_s") as scatter:
+            for pos, (sock, shard, gs) in enumerate(
+                zip(socks, plan.shards[1:], g_shards[1:]), start=1
+            ):
+                ws = self._wire_weights(sock, plan, pos, shard, send_weights)
+                self._write_op(sock, ("bwd", (x, ws, gs)))
         self._seq_issued += 1
         return scheduler.Pending(
-            "bwd", self._seq_issued, x, plan.shards[0], g_shards[0], now,
+            "bwd", self._seq_issued, x, plan.shards[0], g_shards[0], scatter.end,
             plan=plan, parts=socks, g_all=g,
         )
 
@@ -1279,53 +1277,50 @@ class HeteroCluster:
         rows, so the reduction is exact.  Lost participants'
         contributions come from the master's recovery compute."""
         self._check_order(p, "bwd")
-        t0 = time.perf_counter()
-        if p.mode == "batch":
-            r0, r1 = p.rows[0]
-            dx0, dw = self._master_compute(
-                lambda: protocol.bwd_shard(
-                    self._master_backend, p.x[r0:r1], p.my_w, p.my_g
+        with tracing.span("cluster.gather", self.timing, "conv_s"):
+            if p.mode == "batch":
+                r0, r1 = p.rows[0]
+                dx0, dw = self._master_compute(
+                    lambda: protocol.bwd_shard(
+                        self._master_backend, p.x[r0:r1], p.my_w, p.my_g
+                    )
                 )
-            )
-            dxs = [dx0]
-            t_wait = time.perf_counter()
-            for idx, sock in enumerate(p.parts):
-                dx_i, dw_i = self._read_or_recover(sock, p, idx)
-                dxs.append(dx_i)
-                dw = dw + dw_i
-            t1 = time.perf_counter()
-            self._account_gather(p, t0, t_wait, t1)
-            return np.concatenate(dxs, axis=0), dw
-        if p.mode == "spatial":
-            lo, hi, pt, pb = p.halos[0]
-            dxh, dw = self._master_compute(
-                lambda: strip_conv_vjp(
-                    self._master_backend, p.x[:, lo:hi], p.my_w, p.my_g, pt, pb
+                dxs = [dx0]
+                with self._gather_wait(p):
+                    for idx, sock in enumerate(p.parts):
+                        dx_i, dw_i = self._read_or_recover(sock, p, idx)
+                        dxs.append(dx_i)
+                        dw = dw + dw_i
+            elif p.mode == "spatial":
+                lo, hi, pt, pb = p.halos[0]
+                dxh, dw = self._master_compute(
+                    lambda: strip_conv_vjp(
+                        self._master_backend, p.x[:, lo:hi], p.my_w, p.my_g, pt, pb
+                    )
                 )
-            )
-            dx = np.zeros(p.x.shape, np.float32)
-            dx[:, lo:hi] += dxh
-            t_wait = time.perf_counter()
-            for idx, sock in enumerate(p.parts):
-                dxh_i, dw_i = self._read_or_recover(sock, p, idx)
-                lo_i, hi_i, _pt, _pb = p.halos[idx + 1]
-                dx[:, lo_i:hi_i] += dxh_i  # the halo seams overlap-sum here
-                dw = dw + dw_i
-            t1 = time.perf_counter()
-            self._account_gather(p, t0, t_wait, t1)
-            return dx, dw
-        dx, dw0 = self._master_compute(
-            lambda: protocol.bwd_shard(self._master_backend, p.x, p.my_w, p.my_g)
-        )
-        dws = [dw0]
-        t_wait = time.perf_counter()
-        for idx, sock in enumerate(p.parts):
-            dxi, dwi = self._read_or_recover(sock, p, idx)
-            dx = dx + dxi
-            dws.append(dwi)
-        t1 = time.perf_counter()
-        self._account_gather(p, t0, t_wait, t1)
-        return dx, np.concatenate(dws, axis=-1)
+                dx = np.zeros(p.x.shape, np.float32)
+                dx[:, lo:hi] += dxh
+                with self._gather_wait(p):
+                    for idx, sock in enumerate(p.parts):
+                        dxh_i, dw_i = self._read_or_recover(sock, p, idx)
+                        lo_i, hi_i, _pt, _pb = p.halos[idx + 1]
+                        dx[:, lo_i:hi_i] += dxh_i  # the halo seams overlap-sum here
+                        dw = dw + dw_i
+                return dx, dw
+            else:
+                dx, dw0 = self._master_compute(
+                    lambda: protocol.bwd_shard(self._master_backend, p.x, p.my_w, p.my_g)
+                )
+                dws = [dw0]
+                with self._gather_wait(p):
+                    for idx, sock in enumerate(p.parts):
+                        dxi, dwi = self._read_or_recover(sock, p, idx)
+                        dx = dx + dxi
+                        dws.append(dwi)
+        with tracing.span("cluster.assemble"):
+            if p.mode == "batch":
+                return np.concatenate(dxs, axis=0), dw
+            return dx, np.concatenate(dws, axis=-1)
 
     def _check_result(self, out):
         """Re-raise a slave's shipped exception at the gather that would
@@ -1359,8 +1354,14 @@ class HeteroCluster:
         dead member's ROWS from the ranges the op actually shipped
         (``p.rows``, re-cut per slab), not the plan's full-batch
         ranges."""
+        return self._master_work(
+            "cluster.recover", "recompute_s", lambda: self._recompute(p, dev_pos)
+        )
+
+    def _recompute(self, p: scheduler.Pending, dev_pos: int):
+        """Plan position ``dev_pos``'s shard of ``p`` on the master's
+        backend (see ``_recover_shard``)."""
         plan = p.plan
-        t0 = time.perf_counter()
         if p.op == "conv":
             if plan.mode == "kernel":
                 out = protocol.conv_shard(
@@ -1396,11 +1397,6 @@ class HeteroCluster:
                     self._master_backend, p.x[:, lo:hi], plan.w,
                     p.g_all[:, r0:r1], pt, pb,
                 )
-        el = time.perf_counter() - t0
-        if self.slowdowns[0] > 1.0:
-            # reprolint: allow=clock-injection -- slowdown emulation IS a real delay: it stretches measured compute to the emulated device's speed
-            time.sleep(el * (self.slowdowns[0] - 1.0))
-        self.timing.recompute_s += time.perf_counter() - t0
         return out
 
     def _check_order(self, p: scheduler.Pending, op: str):
@@ -1417,27 +1413,33 @@ class HeteroCluster:
         self._seq_gathered = p.seq
 
     def _master_compute(self, fn):
-        t0 = time.perf_counter()
-        out = fn()
-        el = time.perf_counter() - t0
-        if self.slowdowns[0] > 1.0:
-            # reprolint: allow=clock-injection -- slowdown emulation IS a real delay: it stretches measured compute to the emulated device's speed
-            time.sleep(el * (self.slowdowns[0] - 1.0))
-        self.timing.master_conv_s += time.perf_counter() - t0
+        return self._master_work("cluster.master_conv", "master_conv_s", fn)
+
+    def _master_work(self, name: str, field: str, fn):
+        """``fn()`` on the master, stretched by its emulated slowdown,
+        under the span ``name`` that feeds ``LayerTiming.<field>``."""
+        with tracing.span(name, self.timing, field) as s:
+            out = fn()
+            el = time.perf_counter() - s.start
+            if self.slowdowns[0] > 1.0:
+                # reprolint: allow=clock-injection -- slowdown emulation IS a real delay: it stretches measured compute to the emulated device's speed
+                time.sleep(el * (self.slowdowns[0] - 1.0))
         return out
 
-    def _account_gather(self, p: scheduler.Pending, t0, t_wait, t1):
-        self.timing.conv_s += t1 - t0
-        self.timing.gather_wait_s += t1 - t_wait
-        # in-flight window minus the time the master actually blocked:
-        # the comm/compute overlap the pipeline buys
-        self.timing.overlap_s += max(0.0, (t_wait - p.t_issued))
+    @contextlib.contextmanager
+    def _gather_wait(self, p: scheduler.Pending):
+        """The reads of the members' results at a gather: the span
+        ``cluster.gather_wait`` (``gather_wait_s``), and the in-flight
+        window before it (``overlap_s``)."""
+        with tracing.span("cluster.gather_wait", self.timing, "gather_wait_s") as wait:
+            # in-flight window minus the time the master actually blocked:
+            # the comm/compute overlap the pipeline buys
+            self.timing.overlap_s += max(0.0, (wait.start - p.t_issued))
+            yield
 
     def _master_comp(self, f, y: np.ndarray) -> np.ndarray:
-        t0 = time.perf_counter()
-        out = f(y)
-        self.timing.comp_s += time.perf_counter() - t0
-        return out
+        with tracing.span("cluster.stage_fwd", self.timing, "comp_s"):
+            return f(y)
 
     # -- the schedules (core/cluster/scheduler.py) ------------------------
     def _n_micro(self, batch: int) -> int:

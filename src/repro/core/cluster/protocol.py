@@ -64,6 +64,7 @@ from typing import Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.core.cluster.codec import WeightRef
 
 TRAIN_OVER = "trainOver"
@@ -158,33 +159,33 @@ def slave_loop(endpoint, slowdown: float, backend_name: str, device: int):
                     probe_conv_time(backend, slowdown=slowdown, **payload)
                 )
                 continue
-            t0 = time.perf_counter()
-            if op == "conv":
-                x, w = payload
-                w = _resolve_weights(w, op, cached_w, wcache)
-                out = conv_shard(backend, x, w)
-            elif op == "bwd":
-                x, w, g = payload
-                w = _resolve_weights(w, op, cached_w, wcache)
-                out = bwd_shard(backend, x, w, g)
-            elif op == "sconv":  # spatial: a height strip + halo, full kernel
-                from repro.core.backends import strip_conv
+            with tracing.span(f"member.{op}") as work:
+                if op == "conv":
+                    x, w = payload
+                    w = _resolve_weights(w, op, cached_w, wcache)
+                    out = conv_shard(backend, x, w)
+                elif op == "bwd":
+                    x, w, g = payload
+                    w = _resolve_weights(w, op, cached_w, wcache)
+                    out = bwd_shard(backend, x, w, g)
+                elif op == "sconv":  # spatial: a height strip + halo, full kernel
+                    from repro.core.backends import strip_conv
 
-                xh, w, pt, pb = payload
-                w = _resolve_weights(w, op, cached_w, wcache)
-                out = strip_conv(backend, xh, w, pt, pb)
-            elif op == "sbwd":  # spatial backward: halo dX + full-kernel dW
-                from repro.core.backends import strip_conv_vjp
+                    xh, w, pt, pb = payload
+                    w = _resolve_weights(w, op, cached_w, wcache)
+                    out = strip_conv(backend, xh, w, pt, pb)
+                elif op == "sbwd":  # spatial backward: halo dX + full-kernel dW
+                    from repro.core.backends import strip_conv_vjp
 
-                xh, w, g, pt, pb = payload
-                w = _resolve_weights(w, op, cached_w, wcache)
-                out = strip_conv_vjp(backend, xh, w, g, pt, pb)
-            else:  # pragma: no cover
-                raise ValueError(f"unknown op {op}")
-            elapsed = time.perf_counter() - t0
-            if slowdown > 1.0:
-                # reprolint: allow=clock-injection -- slowdown emulation IS a real delay: it stretches measured compute to the emulated device's speed
-                time.sleep(elapsed * (slowdown - 1.0))
+                    xh, w, g, pt, pb = payload
+                    w = _resolve_weights(w, op, cached_w, wcache)
+                    out = strip_conv_vjp(backend, xh, w, g, pt, pb)
+                else:  # pragma: no cover
+                    raise ValueError(f"unknown op {op}")
+                elapsed = time.perf_counter() - work.start
+                if slowdown > 1.0:
+                    # reprolint: allow=clock-injection -- slowdown emulation IS a real delay: it stretches measured compute to the emulated device's speed
+                    time.sleep(elapsed * (slowdown - 1.0))
         except Exception:
             endpoint.send(SlaveError(device, traceback.format_exc()))
             continue

@@ -28,18 +28,25 @@ them as methods.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import tracing
 from repro.core.cluster.plans import LayerPlan, plan_conv
 
 
 @dataclasses.dataclass
 class LayerTiming:
     """Wall-clock breakdown of the cluster's work, accumulated across
-    ops until ``reset_stats``; every field is seconds."""
+    ops until ``reset_stats``; every field is seconds, the summed
+    duration of one named span (``repro.tracing``):
+
+    ``comm_s`` ``cluster.scatter``, ``conv_s`` ``cluster.gather``,
+    ``comp_s`` ``cluster.stage_fwd`` + ``cluster.stage_bwd`` +
+    ``cluster.head``, ``gather_wait_s`` ``cluster.gather_wait``,
+    ``master_conv_s`` ``cluster.master_conv``, ``recompute_s``
+    ``cluster.recover``; ``overlap_s`` is reckoned at each gather."""
 
     comm_s: float = 0.0         # scatter writes (master -> slave links)
     conv_s: float = 0.0         # conv phase: master's shard + gather
@@ -319,9 +326,8 @@ def conv_train_chain(
         f = between[k]
         if f is None:
             return y
-        t0 = time.perf_counter()
-        z, vjp = f(y)
-        cluster.timing.comp_s += time.perf_counter() - t0
+        with tracing.span("cluster.stage_fwd", cluster.timing, "comp_s"):
+            z, vjp = f(y)
         stash_vjp[k][i] = vjp
         return z
 
@@ -330,10 +336,8 @@ def conv_train_chain(
         vjp = stash_vjp[k][i]
         if vjp is None:
             return g
-        t0 = time.perf_counter()
-        gy = vjp(g)
-        cluster.timing.comp_s += time.perf_counter() - t0
-        return gy
+        with tracing.span("cluster.stage_bwd", cluster.timing, "comp_s"):
+            return vjp(g)
 
     # ---- forward phases: layer k's scatters interleave with k-1's
     # gathers (and the between stages between them)
@@ -357,9 +361,8 @@ def conv_train_chain(
     cur = []
     for i in range(n):
         z = fwd_finish(L - 1, i, pend[i])
-        t0 = time.perf_counter()
-        head_aux[i], gz = head(z, i)
-        cluster.timing.comp_s += time.perf_counter() - t0
+        with tracing.span("cluster.head", cluster.timing, "comp_s"):
+            head_aux[i], gz = head(z, i)
         gy = bwd_through(L - 1, i, np.asarray(gz, np.float32))
         cur.append(
             cluster._scatter_bwd_planned(
@@ -373,7 +376,8 @@ def conv_train_chain(
     dw: List[Optional[np.ndarray]] = [None] * L
 
     def acc_dw(k: int, dwi: np.ndarray):
-        dw[k] = dwi if dw[k] is None else dw[k] + dwi
+        with tracing.span("cluster.assemble"):
+            dw[k] = dwi if dw[k] is None else dw[k] + dwi
 
     for k in range(L - 2, -1, -1):
         cur = []

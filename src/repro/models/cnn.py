@@ -22,6 +22,7 @@ from repro.configs.base import CNNConfig
 from repro.layers.conv import apply_conv, conv_axes, init_conv, max_pool
 from repro.layers.linear import apply_dense, dense_axes, init_dense
 from repro.layers.norm import local_response_norm
+from repro.tracing import span, to_device, to_host
 
 
 PAPER_SIZES = {
@@ -175,8 +176,8 @@ def make_cluster_train_step(cluster, cfg: CNNConfig, *, lr: float = 0.05):
         )
 
     def step(params, images, labels):
-        images = np.asarray(images, np.float32)
-        labels = np.asarray(labels)
+        images = to_host(images, np.float32)
+        labels = to_host(labels)
         batch = images.shape[0]
         slices = cluster.microbatch_slices(batch)
         for sl in slices:
@@ -187,54 +188,60 @@ def make_cluster_train_step(cluster, cfg: CNNConfig, *, lr: float = 0.05):
 
         def make_between(k, bias):
             def f(y):
-                y = jnp.asarray(y)
+                y = to_device(y)
                 z = _stage_fwd(y, bias)
 
                 def pull(gz):
-                    gy, gb = _stage_bwd(y, bias, jnp.asarray(gz, jnp.float32))
-                    gb = np.asarray(gb)
+                    gy, gb = _stage_bwd(y, bias, to_device(gz, jnp.float32))
+                    gb = to_host(gb)
                     db[k] = gb if db[k] is None else db[k] + gb
-                    return np.asarray(gy, np.float32)
+                    return to_host(gy, np.float32)
 
-                return np.asarray(z, np.float32), pull
+                return to_host(z, np.float32), pull
             return f
 
         def head(z, i):
-            lbl = jnp.asarray(labels[slices[i]])
+            lbl = to_device(labels[slices[i]])
             loss_i, correct_i, gz, gfc = _head_both(
-                jnp.asarray(z), params["fc"], lbl, jnp.float32(batch)
+                to_device(z), params["fc"], lbl, jnp.float32(batch)
             )
             fc_grad[0] = gfc if fc_grad[0] is None else jax.tree.map(
                 jnp.add, fc_grad[0], gfc
             )
-            return (float(loss_i), float(correct_i)), np.asarray(gz, np.float32)
+            return ((float(to_host(loss_i)), float(to_host(correct_i))),
+                    to_host(gz, np.float32))
+
+        def update(w, dw):
+            with span("cnn.update"):
+                return w - lr * dw
 
         between = [
             make_between(0, params["conv1"]["bias"]),
             make_between(1, params["conv2"]["bias"]),
         ]
-        kernels = [
-            np.asarray(params["conv1"]["kernel"], np.float32),
-            np.asarray(params["conv2"]["kernel"], np.float32),
-        ]
+        with span("cnn.update"):
+            kernels = [
+                to_host(params["conv1"]["kernel"], np.float32),
+                to_host(params["conv2"]["kernel"], np.float32),
+            ]
         new_kernels, res = cluster.conv_train_step(
-            images, kernels, between, head,
-            update=lambda w, dw: w - lr * dw,
+            images, kernels, between, head, update=update,
         )
 
         loss = float(sum(a[0] for a in res.head_aux))
         acc = float(sum(a[1] for a in res.head_aux)) / batch
-        new_params = {
-            "conv1": {
-                "kernel": jnp.asarray(new_kernels[0]),
-                "bias": params["conv1"]["bias"] - lr * db[0],
-            },
-            "conv2": {
-                "kernel": jnp.asarray(new_kernels[1]),
-                "bias": params["conv2"]["bias"] - lr * db[1],
-            },
-            "fc": jax.tree.map(lambda p, g: p - lr * g, params["fc"], fc_grad[0]),
-        }
+        with span("cnn.update"):
+            new_params = {
+                "conv1": {
+                    "kernel": to_device(new_kernels[0]),
+                    "bias": params["conv1"]["bias"] - to_device(lr * db[0]),
+                },
+                "conv2": {
+                    "kernel": to_device(new_kernels[1]),
+                    "bias": params["conv2"]["bias"] - to_device(lr * db[1]),
+                },
+                "fc": jax.tree.map(lambda p, g: p - lr * g, params["fc"], fc_grad[0]),
+            }
         return new_params, loss, acc
 
     return step

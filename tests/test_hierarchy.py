@@ -302,10 +302,15 @@ def test_inner_tier_admit_evict_reprices_group():
     time) — and numerics stay exact throughout."""
     x, w1, w2, g = _data(batch=6)
     want = _single_device_grads(x, w1, w2, g)
-    c = HierarchicalCluster("2x2", microbatches=3)
+    # each group's sub-master 3x slower than its leaf, so evicting the
+    # leaf makes the group 4x slower; with medians of 5 probe timings a
+    # scheduler stall under a parallel test run cannot close that gap
+    c = HierarchicalCluster(
+        parse_groups("2x2", slowdowns=[3.0, 1.0, 3.0, 1.0]), microbatches=3
+    )
     try:
-        t_before = c.probe(image_size=8, in_channels=3, kernel_size=3,
-                           num_kernels=4, batch=4, repeats=1)
+        t_before = c.probe(image_size=16, in_channels=3, kernel_size=3,
+                           num_kernels=16, batch=8, repeats=5)
         inner = c.group_clusters[0]
         root_ids_before = list(c.slave_ids)
         _assert_grads(_train_chain(c, x, w1, w2, g), want)
