@@ -21,11 +21,15 @@ different kernels (the paper's CPU/GPU scenario):
               it raises unless interpret mode is asked for by name
               (``"pallas:interpret"``).
 
-All primitives take and return **numpy** arrays: the master/slave
-protocol moves serialized host buffers (the emulated sockets), and numpy
-is the one currency every backend speaks.  ``probe_conv_time`` times the
-SAME code a device will run for the real workload, so the Eq. 1 shares
-computed from probe times are exact per backend.
+Every primitive takes numpy or ``jax.Array`` inputs and returns its
+result on the side it computes on: ``numpy`` on the host (a device
+input comes down through ``repro.tracing.to_host``), ``xla`` and
+``pallas`` on JAX's default device (only numpy inputs go up, through
+``to_device``), waited for under ``device.wait`` so that a caller's
+clock holds the compute.  The cluster keeps results on the chip
+between its stages (``core/cluster/sides.py``).  ``probe_conv_time``
+times the SAME code a device will run for the real workload, so the
+Eq. 1 shares computed from probe times are exact per backend.
 """
 from __future__ import annotations
 
@@ -34,22 +38,23 @@ from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
-from repro.tracing import to_device, to_host
+from repro.tracing import ready, to_device, to_host
 
 
 class ConvBackend:
     """The per-device compute contract of the distributed conv engine."""
 
     name: str = "base"
+    # computes on the host from numpy: a slave caches its kernels there
+    host: bool = False
 
-    def conv(self, x: np.ndarray, w: np.ndarray) -> np.ndarray:
-        """NHWC x HWIO -> NHWC, SAME padding, stride 1."""
+    def conv(self, x, w):
+        """NHWC x HWIO -> NHWC, SAME padding, stride 1; numpy or device
+        inputs, the result on the side the backend computes on."""
         raise NotImplementedError
 
-    def conv_vjp(
-        self, x: np.ndarray, w: np.ndarray, g: np.ndarray
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """(dx, dw) of sum(conv(x, w) * g)."""
+    def conv_vjp(self, x, w, g):
+        """(dx, dw) of sum(conv(x, w) * g), on the side ``conv``'s is."""
         raise NotImplementedError
 
 
@@ -167,12 +172,13 @@ def numpy_conv_vjp(x: np.ndarray, w: np.ndarray, g: np.ndarray):
 @register_backend("numpy")
 class NumpyBackend(ConvBackend):
     name = "numpy"
+    host = True
 
     def conv(self, x, w):
-        return numpy_conv(x, w)
+        return numpy_conv(to_host(x), to_host(w))
 
     def conv_vjp(self, x, w, g):
-        return numpy_conv_vjp(x, w, g)
+        return numpy_conv_vjp(to_host(x), to_host(w), to_host(g))
 
 
 # ---------------------------------------------------------------------------
@@ -198,7 +204,10 @@ def strip_conv(
     SAME conv on the padded strip and slices out the interior rows —
     every backend works unchanged.  Assumes odd ``kh`` (the repo's
     ``kh//2``-low padding convention; even kernels differ per backend).
-    Returns the strip's output rows: (B, strip_h, W, cout)."""
+    Returns the strip's output rows: (B, strip_h, W, cout), on the host:
+    the strip arithmetic pads and slices in numpy, so a device input
+    comes down through ``to_host`` and the backend's result comes back
+    the same way."""
     kh = w.shape[0]
     ph = kh // 2
     strip_h = x_halo.shape[1] + pad_top + pad_bot - (kh - 1)
@@ -206,9 +215,9 @@ def strip_conv(
         return np.zeros(
             (x_halo.shape[0], 0, x_halo.shape[2], w.shape[-1]), np.float32
         )
-    xp = np.pad(x_halo, ((0, 0), (pad_top, pad_bot), (0, 0), (0, 0)))
-    y = backend.conv(xp, w)
-    return np.asarray(y[:, ph : ph + strip_h], np.float32)
+    xp = np.pad(to_host(x_halo), ((0, 0), (pad_top, pad_bot), (0, 0), (0, 0)))
+    y = to_host(backend.conv(xp, w), np.float32)
+    return y[:, ph : ph + strip_h]
 
 
 def strip_conv_vjp(
@@ -225,7 +234,8 @@ def strip_conv_vjp(
     this strip's output-gradient rows to neighbouring strips' inputs —
     so the master must overlap-ADD the seams when reassembling the full
     dX.  ``dw_partial`` is this strip's contribution to the FULL kernel
-    gradient (strips see every output channel); the master sums it."""
+    gradient (strips see every output channel); the master sums it.
+    Both on the host, like ``strip_conv``'s result."""
     kh = w.shape[0]
     ph = kh // 2
     strip_h = g_strip.shape[1]
@@ -234,12 +244,12 @@ def strip_conv_vjp(
             np.zeros(x_halo.shape, np.float32),
             np.zeros(w.shape, np.float32),
         )
-    xp = np.pad(x_halo, ((0, 0), (pad_top, pad_bot), (0, 0), (0, 0)))
+    xp = np.pad(to_host(x_halo), ((0, 0), (pad_top, pad_bot), (0, 0), (0, 0)))
     gp = np.zeros(xp.shape[:-1] + (w.shape[-1],), np.float32)
-    gp[:, ph : ph + strip_h] = g_strip
+    gp[:, ph : ph + strip_h] = to_host(g_strip)
     dxp, dw = backend.conv_vjp(xp, w, gp)
-    dx_halo = dxp[:, pad_top : pad_top + x_halo.shape[1]]
-    return np.asarray(dx_halo, np.float32), np.asarray(dw, np.float32)
+    dxp = to_host(dxp, np.float32)
+    return dxp[:, pad_top : pad_top + x_halo.shape[1]], to_host(dw, np.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -267,16 +277,13 @@ class XlaBackend(ConvBackend):
         self._conv = jax.jit(_conv)
         self._vjp = jax.jit(_vjp)
 
-    # the inputs are put on JAX's default device explicitly, as the jit
-    # call would put them, so that the upload is timed and counted
+    # numpy inputs are put on JAX's default device explicitly, as the
+    # jit call would put them, so that the upload is timed and counted
     def conv(self, x, w):
-        return to_host(self._conv(to_device(np.asarray(x)), to_device(np.asarray(w))))
+        return ready(self._conv(to_device(x), to_device(w)))
 
     def conv_vjp(self, x, w, g):
-        dx, dw = self._vjp(
-            to_device(np.asarray(x)), to_device(np.asarray(w)), to_device(np.asarray(g))
-        )
-        return to_host(dx), to_host(dw)
+        return ready(self._vjp(to_device(x), to_device(w), to_device(g)))
 
 
 # ---------------------------------------------------------------------------
@@ -334,7 +341,7 @@ class PallasBackend(ConvBackend):
     def conv(self, x, w):
         from repro.kernels.conv2d import conv2d_pallas
 
-        return to_host(
+        return ready(
             conv2d_pallas(to_device(x), to_device(w), interpret=self.interpret)
         )
 
@@ -342,11 +349,10 @@ class PallasBackend(ConvBackend):
         from repro.kernels.conv2d import conv2d_dw_pallas, conv2d_dx_pallas
 
         kh, kw = w.shape[0], w.shape[1]
-        dx = conv2d_dx_pallas(to_device(g), to_device(w), interpret=self.interpret)
-        dw = conv2d_dw_pallas(
-            to_device(x), to_device(g), kh, kw, interpret=self.interpret
-        )
-        return to_host(dx), to_host(dw)
+        g = to_device(g)
+        dx = conv2d_dx_pallas(g, to_device(w), interpret=self.interpret)
+        dw = conv2d_dw_pallas(to_device(x), g, kh, kw, interpret=self.interpret)
+        return ready((dx, dw))
 
 
 # ---------------------------------------------------------------------------
@@ -359,7 +365,8 @@ class SimBackend(ConvBackend):
     """Sleeps exactly ``flops / flops_per_s`` and returns ZEROS of the
     right shape.  Wall-clock behaves like a device of known speed with
     none of the host's compute noise — for benchmarking the master/slave
-    protocol schedule (bench_master_slave.py), NEVER for numerics."""
+    protocol schedule (bench_master_slave.py), NEVER for numerics.  It
+    reads only its inputs' shapes, so a device input never crosses."""
 
     name = "sim"
 

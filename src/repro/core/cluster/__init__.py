@@ -5,6 +5,8 @@
                    framed sockets, subprocess slaves)
     codec.py     — the fp16/bf16 compact wire codec + canonical byte
                    accounting, independent of any transport
+    sides.py     — cuts, casts, concatenations and sums of arrays where
+                   they live (device or host), crossings counted
     protocol.py  — message grammar + the slave loop (Algorithm 2);
                    doubles as the TCP slave process entry (``-m``)
     plans.py     — per-layer partition plans: kernel/spatial/auto axis

@@ -84,7 +84,7 @@ from repro.core.backends import (
     strip_conv,
     strip_conv_vjp,
 )
-from repro.core.cluster import codec, plans, protocol, scheduler
+from repro.core.cluster import codec, plans, protocol, scheduler, sides
 from repro.core.cluster.transport import (
     TRANSPORT_KINDS,
     InProcTransport,
@@ -1071,7 +1071,7 @@ class HeteroCluster:
         height strips + the full kernel (spatial mode), or batch-row
         slices + the replicated kernel (batch mode); returns a handle.
         The master's own shard runs at gather time."""
-        x = np.asarray(x, np.float32)
+        x = sides.float32(x)
         plan = self.plan_conv(x.shape, w, "conv", partition)
         return self._scatter_conv_planned(x, plan, send_weights=True)
 
@@ -1099,12 +1099,12 @@ class HeteroCluster:
                 zip(socks, plan.halos[1:]), start=1
             ):
                 ws = self._wire_weights(sock, plan, pos, plan.w, send_weights)
-                self._write_op(sock, ("sconv", (x[:, lo:hi], ws, pt, pb)))
+                xs = sides.cut(x, lo, hi, axis=1)
+                self._write_op(sock, ("sconv", (xs, ws, pt, pb)))
         self._seq_issued += 1
         return scheduler.Pending(
-            "conv", self._seq_issued, x, plan.w, None, scatter.end,
-            mode="spatial", rows=plan.rows, halos=plan.halos,
-            plan=plan, parts=socks,
+            "conv", self._seq_issued, x, scatter.end, mode="spatial",
+            rows=plan.rows, halos=plan.halos, plan=plan, parts=socks,
         )
 
     def _scatter_conv_shards(
@@ -1121,8 +1121,7 @@ class HeteroCluster:
                 self._write_op(sock, ("conv", (x, ws)))
         self._seq_issued += 1
         return scheduler.Pending(
-            "conv", self._seq_issued, x, plan.shards[0], None, scatter.end,
-            plan=plan, parts=socks,
+            "conv", self._seq_issued, x, scatter.end, plan=plan, parts=socks,
         )
 
     def _scatter_conv_batch(
@@ -1140,11 +1139,11 @@ class HeteroCluster:
         with tracing.span("cluster.scatter", self.timing, "comm_s") as scatter:
             for pos, (sock, (r0, r1)) in enumerate(zip(socks, rows[1:]), start=1):
                 ws = self._wire_weights(sock, plan, pos, plan.w, send_weights)
-                self._write_op(sock, ("conv", (x[r0:r1], ws)))
+                self._write_op(sock, ("conv", (sides.cut(x, r0, r1), ws)))
         self._seq_issued += 1
         return scheduler.Pending(
-            "conv", self._seq_issued, x, plan.w, None, scatter.end,
-            mode="batch", rows=rows, plan=plan, parts=socks,
+            "conv", self._seq_issued, x, scatter.end, mode="batch", rows=rows,
+            plan=plan, parts=socks,
         )
 
     def gather_conv(self, p: scheduler.Pending) -> np.ndarray:
@@ -1153,34 +1152,13 @@ class HeteroCluster:
         along channels (kernel mode), height (spatial strips), or the
         N axis (batch rows).  A participant lost since the scatter
         contributes via the master's recovery compute instead of the
-        wire."""
+        wire.  The parts are assembled where they live (``_assemble``)."""
         self._check_order(p, "conv")
-        with tracing.span("cluster.gather", self.timing, "conv_s"):
-            if p.mode == "spatial":
-                lo, hi, pt, pb = p.halos[0]
-                my_out = self._master_compute(
-                    lambda: strip_conv(self._master_backend, p.x[:, lo:hi], p.my_w, pt, pb)
-                )
-                axis = 1
-            elif p.mode == "batch":
-                r0, r1 = p.rows[0]
-                my_out = self._master_compute(
-                    lambda: protocol.conv_shard(
-                        self._master_backend, p.x[r0:r1], p.my_w
-                    )
-                )
-                axis = 0
-            else:
-                my_out = self._master_compute(
-                    lambda: protocol.conv_shard(self._master_backend, p.x, p.my_w)
-                )
-                axis = -1
-            outs = [my_out]
-            with self._gather_wait(p):
-                for idx, sock in enumerate(p.parts):
-                    outs.append(self._read_or_recover(sock, p, idx))
-        with tracing.span("cluster.assemble"):
-            return np.concatenate(outs, axis=axis)
+        outs = self._gather_parts(p)
+        axis = {"spatial": 1, "batch": 0}.get(p.mode, -1)
+        return self._assemble(
+            p, outs, lambda parts, dev: sides.concat(parts, axis, dev)
+        )
 
     def scatter_bwd(
         self, x: np.ndarray, w: np.ndarray, g: np.ndarray,
@@ -1199,8 +1177,8 @@ class HeteroCluster:
         Returns:
             The in-flight ``Pending`` (op ``"bwd"``) to gather.
         """
-        x = np.asarray(x, np.float32)
-        g = np.asarray(g, np.float32)
+        x = sides.float32(x)
+        g = sides.float32(g)
         plan = self.plan_conv(x.shape, w, "bwd", partition)
         return self._scatter_bwd_planned(x, plan, g, send_weights=True)
 
@@ -1218,15 +1196,12 @@ class HeteroCluster:
                 zip(socks, plan.rows[1:], plan.halos[1:]), start=1
             ):
                 ws = self._wire_weights(sock, plan, pos, plan.w, send_weights)
-                self._write_op(
-                    sock, ("sbwd", (x[:, lo:hi], ws, g[:, r0:r1], pt, pb))
-                )
+                xs, gs = sides.cut(x, lo, hi, axis=1), sides.cut(g, r0, r1, axis=1)
+                self._write_op(sock, ("sbwd", (xs, ws, gs, pt, pb)))
         self._seq_issued += 1
-        r0, r1 = plan.rows[0]
         return scheduler.Pending(
-            "bwd", self._seq_issued, x, plan.w, g[:, r0:r1], scatter.end,
-            mode="spatial", rows=plan.rows, halos=plan.halos,
-            plan=plan, parts=socks, g_all=g,
+            "bwd", self._seq_issued, x, scatter.end, mode="spatial",
+            rows=plan.rows, halos=plan.halos, plan=plan, parts=socks, g_all=g,
         )
 
     def _scatter_bwd_batch(
@@ -1242,12 +1217,12 @@ class HeteroCluster:
         with tracing.span("cluster.scatter", self.timing, "comm_s") as scatter:
             for pos, (sock, (r0, r1)) in enumerate(zip(socks, rows[1:]), start=1):
                 ws = self._wire_weights(sock, plan, pos, plan.w, send_weights)
-                self._write_op(sock, ("bwd", (x[r0:r1], ws, g[r0:r1])))
+                xs, gs = sides.cut(x, r0, r1), sides.cut(g, r0, r1)
+                self._write_op(sock, ("bwd", (xs, ws, gs)))
         self._seq_issued += 1
-        r0, r1 = rows[0]
         return scheduler.Pending(
-            "bwd", self._seq_issued, x, plan.w, g[r0:r1], scatter.end,
-            mode="batch", rows=rows, plan=plan, parts=socks, g_all=g,
+            "bwd", self._seq_issued, x, scatter.end, mode="batch", rows=rows,
+            plan=plan, parts=socks, g_all=g,
         )
 
     def _scatter_bwd_shards(
@@ -1255,17 +1230,16 @@ class HeteroCluster:
         send_weights: bool,
     ) -> scheduler.Pending:
         socks = self._plan_sockets(plan)
-        g_shards = self._split(g, plan.counts)
+        edges = plans.kernel_edges(plan.counts)  # the master cuts its own slice
         with tracing.span("cluster.scatter", self.timing, "comm_s") as scatter:
-            for pos, (sock, shard, gs) in enumerate(
-                zip(socks, plan.shards[1:], g_shards[1:]), start=1
-            ):
+            for pos, (sock, shard) in enumerate(zip(socks, plan.shards[1:]), start=1):
                 ws = self._wire_weights(sock, plan, pos, shard, send_weights)
+                gs = sides.cut(g, edges[pos], edges[pos + 1], axis=-1)
                 self._write_op(sock, ("bwd", (x, ws, gs)))
         self._seq_issued += 1
         return scheduler.Pending(
-            "bwd", self._seq_issued, x, plan.shards[0], g_shards[0], scatter.end,
-            plan=plan, parts=socks, g_all=g,
+            "bwd", self._seq_issued, x, scatter.end, plan=plan, parts=socks,
+            g_all=g,
         )
 
     def gather_bwd(self, p: scheduler.Pending) -> Tuple[np.ndarray, np.ndarray]:
@@ -1275,52 +1249,53 @@ class HeteroCluster:
         dW contributions.  Batch mode: concat dX rows along the N axis
         and SUM the per-member full dW — dW is a sum over disjoint batch
         rows, so the reduction is exact.  Lost participants'
-        contributions come from the master's recovery compute."""
+        contributions come from the master's recovery compute.  Sums run
+        left to right in plan order, on either side."""
         self._check_order(p, "bwd")
-        with tracing.span("cluster.gather", self.timing, "conv_s"):
-            if p.mode == "batch":
-                r0, r1 = p.rows[0]
-                dx0, dw = self._master_compute(
-                    lambda: protocol.bwd_shard(
-                        self._master_backend, p.x[r0:r1], p.my_w, p.my_g
-                    )
-                )
-                dxs = [dx0]
-                with self._gather_wait(p):
-                    for idx, sock in enumerate(p.parts):
-                        dx_i, dw_i = self._read_or_recover(sock, p, idx)
-                        dxs.append(dx_i)
-                        dw = dw + dw_i
-            elif p.mode == "spatial":
-                lo, hi, pt, pb = p.halos[0]
-                dxh, dw = self._master_compute(
-                    lambda: strip_conv_vjp(
-                        self._master_backend, p.x[:, lo:hi], p.my_w, p.my_g, pt, pb
-                    )
-                )
+        outs = self._gather_parts(p)
+
+        def combine(parts, dev):
+            dxs, dws = [dx for dx, _ in parts], [dw for _, dw in parts]
+            if p.mode == "spatial":  # on the host: the strips are host arrays
                 dx = np.zeros(p.x.shape, np.float32)
-                dx[:, lo:hi] += dxh
-                with self._gather_wait(p):
-                    for idx, sock in enumerate(p.parts):
-                        dxh_i, dw_i = self._read_or_recover(sock, p, idx)
-                        lo_i, hi_i, _pt, _pb = p.halos[idx + 1]
-                        dx[:, lo_i:hi_i] += dxh_i  # the halo seams overlap-sum here
-                        dw = dw + dw_i
-                return dx, dw
-            else:
-                dx, dw0 = self._master_compute(
-                    lambda: protocol.bwd_shard(self._master_backend, p.x, p.my_w, p.my_g)
-                )
-                dws = [dw0]
-                with self._gather_wait(p):
-                    for idx, sock in enumerate(p.parts):
-                        dxi, dwi = self._read_or_recover(sock, p, idx)
-                        dx = dx + dxi
-                        dws.append(dwi)
-        with tracing.span("cluster.assemble"):
+                for dxh, (lo, hi, _pt, _pb) in zip(dxs, p.halos):
+                    dx[:, lo:hi] += tracing.to_host(dxh)  # the halo seams sum here
+                return dx, sides.total(dws, False)
             if p.mode == "batch":
-                return np.concatenate(dxs, axis=0), dw
-            return dx, np.concatenate(dws, axis=-1)
+                return sides.concat(dxs, 0, dev), sides.total(dws, dev)
+            return sides.total(dxs, dev), sides.concat(dws, -1, dev)
+
+        return self._assemble(p, outs, combine)
+
+    def _gather_parts(self, p: scheduler.Pending) -> list:
+        """Every plan position's part of ``p``, in plan order: the
+        master's own shard (``cluster.master_conv``), then each member's
+        read from its link, or recomputed here if it was lost — all
+        under ``cluster.gather``."""
+        with tracing.span("cluster.gather", self.timing, "conv_s"):
+            outs = [self._master_compute(lambda: self._recompute(p, 0))]
+            with self._gather_wait(p):
+                for idx, sock in enumerate(p.parts):
+                    outs.append(self._read_or_recover(sock, p, idx))
+        return outs
+
+    def _assemble(self, p: scheduler.Pending, parts: list, combine):
+        """``combine(parts, on_device)`` under ``cluster.assemble``: on the
+        device where any part (or either half of a ``(dX, dW)`` part) is a
+        ``jax.Array``, the host parts going up through ``to_device``;
+        else on the host, as before.  Counts the side
+        (``tracing.count_assembly``) and hands the result back on the
+        side of the op's input ``p.x``, crossing only where the two
+        differ: numpy callers get numpy."""
+        flat = [a for part in parts for a in (part if isinstance(part, tuple) else (part,))]
+        dev = any(sides.on_device(a) for a in flat)
+        want = sides.on_device(p.x)
+        with tracing.span("cluster.assemble"):
+            tracing.count_assembly(dev)
+            out = combine(parts, dev)
+            if isinstance(out, tuple):
+                return tuple(sides.to_side(o, want) for o in out)
+            return sides.to_side(out, want)
 
     def _check_result(self, out):
         """Re-raise a slave's shipped exception at the gather that would
@@ -1360,7 +1335,8 @@ class HeteroCluster:
 
     def _recompute(self, p: scheduler.Pending, dev_pos: int):
         """Plan position ``dev_pos``'s shard of ``p`` on the master's
-        backend (see ``_recover_shard``)."""
+        backend: its own shard at position 0, a lost member's in
+        recovery (see ``_recover_shard``)."""
         plan = p.plan
         if p.op == "conv":
             if plan.mode == "kernel":
@@ -1370,32 +1346,33 @@ class HeteroCluster:
             elif plan.mode == "batch":
                 r0, r1 = p.rows[dev_pos]
                 out = protocol.conv_shard(
-                    self._master_backend, p.x[r0:r1], plan.w
+                    self._master_backend, sides.cut(p.x, r0, r1), plan.w
                 )
             else:
                 lo, hi, pt, pb = plan.halos[dev_pos]
                 out = strip_conv(
-                    self._master_backend, p.x[:, lo:hi], plan.w, pt, pb
+                    self._master_backend, sides.cut(p.x, lo, hi, axis=1),
+                    plan.w, pt, pb,
                 )
         else:
             if plan.mode == "kernel":
-                gs = plans.split_kernels(p.g_all, plan.counts)
+                edges = plans.kernel_edges(plan.counts)
+                g = sides.cut(p.g_all, edges[dev_pos], edges[dev_pos + 1], axis=-1)
                 out = protocol.bwd_shard(
-                    self._master_backend, p.x, plan.shards[dev_pos],
-                    gs[dev_pos],
+                    self._master_backend, p.x, plan.shards[dev_pos], g
                 )
             elif plan.mode == "batch":
                 r0, r1 = p.rows[dev_pos]
                 out = protocol.bwd_shard(
-                    self._master_backend, p.x[r0:r1], plan.w,
-                    p.g_all[r0:r1],
+                    self._master_backend, sides.cut(p.x, r0, r1), plan.w,
+                    sides.cut(p.g_all, r0, r1),
                 )
             else:
                 r0, r1 = plan.rows[dev_pos]
                 lo, hi, pt, pb = plan.halos[dev_pos]
                 out = strip_conv_vjp(
-                    self._master_backend, p.x[:, lo:hi], plan.w,
-                    p.g_all[:, r0:r1], pt, pb,
+                    self._master_backend, sides.cut(p.x, lo, hi, axis=1), plan.w,
+                    sides.cut(p.g_all, r0, r1, axis=1), pt, pb,
                 )
         return out
 

@@ -29,6 +29,13 @@ transports count with the SAME function, so ``comm_bytes`` is
 comparable between the in-process emulation, a real TCP wire and the
 shared-memory rings.
 
+A ``jax.Array`` leaf (a shard that stays on the chip, on an in-process
+link) counts its ``nbytes`` like the numpy array it would be, and a
+message class left at fp32 passes it unchanged.  A narrowing stage
+first brings it to the host through ``repro.tracing.to_host``, and so
+does ``host_arrays``, which a real wire (tcp, shm) applies to every
+message before it is pickled.
+
 ``WeightRef`` is the versioned weight-broadcast cache's wire token: the
 weight slot of an op may carry ``WeightRef(key, version, w)`` to prime
 a slave's cache, or ``WeightRef(key, version, None)`` — ~24 bytes — to
@@ -40,9 +47,12 @@ this module before any heavy framework lands.
 """
 from __future__ import annotations
 
+import sys
 from typing import Dict, Optional, Tuple
 
 import numpy as np
+
+from repro.tracing import on_device, to_host
 
 MESSAGE_CLASSES = ("weights", "acts", "grads")
 
@@ -163,6 +173,24 @@ class WeightRef:
         self.w = w
 
 
+def _array_types() -> tuple:
+    """The array leaf types a message may hold: numpy, plus ``jax.Array``
+    once jax is imported."""
+    jax = sys.modules.get("jax")
+    return (np.ndarray,) if jax is None else (np.ndarray, jax.Array)
+
+
+def _is_array(obj) -> bool:
+    return isinstance(obj, np.ndarray) or on_device(obj)
+
+
+def host_arrays(obj):
+    """``obj`` with every ``jax.Array`` leaf brought to the host through
+    ``repro.tracing.to_host`` — what a real wire needs before pickling."""
+    jax = sys.modules.get("jax")
+    return obj if jax is None else map_arrays(obj, to_host, leaf=jax.Array)
+
+
 def map_arrays(obj, fn, leaf=np.ndarray):
     """Rebuild ``obj`` with ``fn`` applied to every ``leaf`` instance,
     descending through tuples/lists/dicts AND the codec's own marker
@@ -195,8 +223,9 @@ def wire_nbytes(obj) -> int:
     """Canonical bytes-on-the-wire of a message — called AFTER encoding,
     so counters and bandwidth emulation see the codec's compacted size.
     Dict keys count at the 8-byte scalar rate like every other
-    non-array token."""
-    if isinstance(obj, np.ndarray):
+    non-array token.  A ``jax.Array`` counts like the numpy array it
+    would be on the host."""
+    if _is_array(obj):
         return obj.nbytes
     if isinstance(obj, (tuple, list)):
         return sum(wire_nbytes(o) for o in obj)
@@ -389,7 +418,13 @@ class WireCodec:
     # -- stages ------------------------------------------------------
 
     def _stage_arr(self, a, stage):
-        """Apply one stage to one leaf array (non-float leaves pass)."""
+        """Apply one stage to one leaf array (non-float leaves pass).  A
+        device array passes the fp32 stage unchanged; a narrowing stage
+        works on its host copy."""
+        if on_device(a):
+            if stage is None:
+                return a
+            a = to_host(a)
         if not isinstance(a, np.ndarray) or a.dtype not in _FLOATS:
             return a
         if stage == "int8":
@@ -400,7 +435,9 @@ class WireCodec:
 
     def _apply(self, obj, stage):
         """One stage over a whole subtree."""
-        return map_arrays(obj, lambda a: self._stage_arr(a, stage))
+        return map_arrays(
+            obj, lambda a: self._stage_arr(a, stage), leaf=_array_types()
+        )
 
     def _weight_slot(self, w):
         """Encode an op's weight slot: raw kernel, ``None`` (the legacy
@@ -418,8 +455,8 @@ class WireCodec:
         feedback when configured, else the dense grads stage."""
         if self.grad_topk is None:
             return self._apply(g, self.grads)
-        key = (wkey, tuple(np.shape(g)))
-        g_eff = np.asarray(g, np.float32)
+        key = (wkey, tuple(g.shape))
+        g_eff = to_host(g, np.float32)
         resid = self._ef.get(key)
         if resid is not None and resid.shape == g_eff.shape:
             g_eff = g_eff + resid
@@ -469,7 +506,7 @@ class WireCodec:
         ``(dX, dW)`` (grads class), anything else is activations."""
         if (
             isinstance(msg, tuple) and len(msg) == 2
-            and all(isinstance(o, np.ndarray) for o in msg)
+            and all(_is_array(o) for o in msg)
         ):
             return tuple(self._apply(o, self.grads) for o in msg)
         return self._apply(msg, self.acts)

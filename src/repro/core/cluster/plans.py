@@ -10,7 +10,8 @@ geometry, batch-row ranges, per-unit wire bytes, the wall-clock
 predictor and the axis resolver — over a duck-typed ``cluster`` that
 supplies device state (``_effective_times``, ``shares_for``,
 ``bandwidths``, ``probe_flops``, ``_wire_itemsize``, ``partition``,
-``partition_choices``).  No transport, no threads, numpy only.
+``partition_choices``).  No transport, no threads; kernels are cut
+where they live (``sides.cut``), on the host or the device.
 """
 from __future__ import annotations
 
@@ -18,6 +19,8 @@ import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro.core.cluster import sides
 
 PARTITION_MODES = ("kernel", "spatial", "batch", "auto")
 
@@ -121,10 +124,17 @@ class LayerPlan:
     wversion: int = 0
 
 
+def kernel_edges(counts) -> np.ndarray:
+    """Output-channel offsets of the per-device shards: device ``i``
+    holds channels ``[edges[i], edges[i + 1])``."""
+    return np.concatenate([[0], np.cumsum(counts)]).astype(int)
+
+
 def split_kernels(w: np.ndarray, counts: np.ndarray) -> List[np.ndarray]:
-    """Split the kernel's output-channel axis into per-device shards."""
-    edges = np.cumsum(counts)[:-1]
-    return np.split(w, edges, axis=-1)
+    """Split the kernel's output-channel axis into per-device shards, on
+    the side ``w`` lives on."""
+    edges = kernel_edges(counts)
+    return [sides.cut(w, lo, hi, axis=-1) for lo, hi in zip(edges[:-1], edges[1:])]
 
 
 def unit_bytes(
@@ -379,14 +389,14 @@ def plan_conv(
         # scatter re-cuts ``counts`` to its slab via ``batch_ranges``
         counts = cluster.shares_for(b, unit_bytes=ub, layer_flops=layer_flops)
         return LayerPlan(
-            "batch", counts, w=np.asarray(w, np.float32),
+            "batch", counts, w=sides.float32(w),
             rows=batch_ranges(counts, int(b)),
             member_ids=members, wkey=wkey, wversion=wversion,
         )
     counts = cluster.shares_for(h, unit_bytes=ub, layer_flops=layer_flops)
     rows, halos = strip_plan(h, kh, counts)
     return LayerPlan(
-        "spatial", counts, w=np.asarray(w, np.float32), rows=rows,
+        "spatial", counts, w=sides.float32(w), rows=rows,
         halos=halos, member_ids=members, wkey=wkey, wversion=wversion,
     )
 

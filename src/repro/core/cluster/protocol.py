@@ -65,7 +65,7 @@ from typing import Tuple
 import numpy as np
 
 from repro import tracing
-from repro.core.cluster.codec import WeightRef
+from repro.core.cluster.codec import WeightRef, host_arrays
 
 TRAIN_OVER = "trainOver"
 
@@ -131,7 +131,9 @@ def slave_loop(endpoint, slowdown: float, backend_name: str, device: int):
     inputs/kernels, convolve with this device's backend, write outputs.
     No per-op ack: the master may queue several ops ahead (the pipeline);
     results stream back in issue order.  Returns on "trainOver" or when
-    the master's side of the link goes away (EOF)."""
+    the master's side of the link goes away (EOF).  A host backend
+    (``numpy``) brings device payloads down on receipt, so the kernels
+    it caches are host copies made once."""
     backend = None
     cached_w = {}  # last kernel shard per op: pipelined microbatches after
     #                the first send w=None instead of retransmitting it
@@ -160,6 +162,8 @@ def slave_loop(endpoint, slowdown: float, backend_name: str, device: int):
                 )
                 continue
             with tracing.span(f"member.{op}") as work:
+                if getattr(backend, "host", False):
+                    payload = host_arrays(payload)
                 if op == "conv":
                     x, w = payload
                     w = _resolve_weights(w, op, cached_w, wcache)

@@ -23,7 +23,10 @@ transport behind the channel would need a window of that many messages
 
 Every driver takes the cluster as its first argument and runs over
 whatever transport the cluster was built on; ``HeteroCluster`` exposes
-them as methods.
+them as methods.  Each works on the side its input lives on: given a
+``jax.Array`` it keeps activations, gradients and results on the device
+between gathers (the train step's path); given numpy it hands numpy to
+its stages and back to its caller, as before (``sides.py``).
 """
 from __future__ import annotations
 
@@ -33,6 +36,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro import tracing
+from repro.core.cluster import sides
 from repro.core.cluster.plans import LayerPlan, plan_conv
 
 
@@ -72,7 +76,8 @@ class TrainStepResult:
 @dataclasses.dataclass
 class Pending:
     """An in-flight scatter: the master's own shard is deferred to the
-    gather so issuing the NEXT scatter never waits on local compute.
+    gather so issuing the NEXT scatter never waits on local compute; it
+    is cut from the plan there, like a lost member's.
 
     An elastic cluster may lose a slave between this scatter and its
     gather, so a Pending carries enough to finish WITHOUT that slave:
@@ -89,8 +94,6 @@ class Pending:
     #                               spatial/batch: the FULL input (the
     #                               master slices its own strip/rows at
     #                               gather)
-    my_w: np.ndarray              # master's kernel shard (spatial/batch: full w)
-    my_g: Optional[np.ndarray]    # bwd only: master's grad slice/strip/rows
     t_issued: float
     mode: str = "kernel"          # partition axis this op was split on
     rows: Optional[List[Tuple[int, int]]] = None
@@ -119,6 +122,11 @@ def microbatch_slices(cluster, batch: int) -> List[slice]:
     return out
 
 
+def _microbatches(cluster, x) -> list:
+    """``x`` cut along ``microbatch_slices``, on the side it lives on."""
+    return [sides.cut(x, sl.start, sl.stop) for sl in microbatch_slices(cluster, x.shape[0])]
+
+
 def conv_forward(
     cluster, x: np.ndarray, w: np.ndarray, *, partition: Optional[str] = None
 ) -> np.ndarray:
@@ -126,12 +134,11 @@ def conv_forward(
     Pipelined mode double-buffers microbatches along the batch axis
     (orthogonal to either split axis); the plan — and so the kernel
     shard each slave caches — is fixed across the microbatches."""
-    x = np.asarray(x, np.float32)
+    x = sides.float32(x)
     plan = plan_conv(cluster, x.shape, w, "conv", partition)
-    n = cluster._n_micro(x.shape[0])
-    if n == 1:
+    parts = _microbatches(cluster, x)
+    if len(parts) == 1:
         return cluster.gather_conv(cluster._scatter_conv_planned(x, plan, True))
-    parts = np.array_split(x, n, axis=0)
     outs = []
     pending = cluster._scatter_conv_planned(parts[0], plan, True)
     for nxt in parts[1:]:
@@ -140,7 +147,7 @@ def conv_forward(
         outs.append(cluster.gather_conv(pending))
         pending = nxt_pending
     outs.append(cluster.gather_conv(pending))
-    return np.concatenate(outs, axis=0)
+    return sides.concat(outs, axis=0)
 
 
 def conv_backward(
@@ -152,27 +159,26 @@ def conv_backward(
     seam-sums halo'd dX strips and sums full-kernel dW parts.
     Pipelined mode double-buffers microbatches; per-microbatch dW
     contributions are summed."""
-    x = np.asarray(x, np.float32)
-    g = np.asarray(g, np.float32)
+    x = sides.float32(x)
+    g = sides.float32(g)
     plan = plan_conv(cluster, x.shape, w, "bwd", partition)
-    n = cluster._n_micro(x.shape[0])
-    if n == 1:
+    xs = _microbatches(cluster, x)
+    if len(xs) == 1:
         return cluster.gather_bwd(cluster._scatter_bwd_planned(x, plan, g, True))
-    xs = np.array_split(x, n, axis=0)
-    gs = np.array_split(g, n, axis=0)
+    gs = _microbatches(cluster, g)
     dxs: List[np.ndarray] = []
-    dw_total: Optional[np.ndarray] = None
+    dws: List[np.ndarray] = []
     pending = cluster._scatter_bwd_planned(xs[0], plan, gs[0], True)
     for xi, gi in zip(xs[1:], gs[1:]):
         nxt_pending = cluster._scatter_bwd_planned(xi, plan, gi, False)
         dx_i, dw_i = cluster.gather_bwd(pending)
         dxs.append(dx_i)
-        dw_total = dw_i if dw_total is None else dw_total + dw_i
+        dws.append(dw_i)
         pending = nxt_pending
     dx_i, dw_i = cluster.gather_bwd(pending)
     dxs.append(dx_i)
-    dw_total = dw_i if dw_total is None else dw_total + dw_i
-    return np.concatenate(dxs, axis=0), dw_total
+    dws.append(dw_i)
+    return sides.concat(dxs, axis=0), sides.total(dws)
 
 
 def group_forward(cluster, x: np.ndarray, w: np.ndarray) -> np.ndarray:
@@ -184,7 +190,7 @@ def group_forward(cluster, x: np.ndarray, w: np.ndarray) -> np.ndarray:
     or a zero-kernel layer never touches the inner planner — batch
     plans require at least one row — and returns the exact
     correctly-shaped zero-size result instead."""
-    x = np.asarray(x, np.float32)
+    x = sides.float32(x)
     if x.shape[0] == 0 or w.shape[-1] == 0:
         return np.zeros(x.shape[:3] + (w.shape[-1],), np.float32)
     return conv_forward(cluster, x, w)
@@ -201,13 +207,13 @@ def group_backward(
     proved, just with groups as the members.  Zero-row / zero-kernel
     slices short-circuit to zero arrays (a zero dW contribution is the
     correct term for a group holding no rows)."""
-    x = np.asarray(x, np.float32)
+    x = sides.float32(x)
     if x.shape[0] == 0 or w.shape[-1] == 0:
         return (
             np.zeros(x.shape, np.float32),
             np.zeros(w.shape, np.float32),
         )
-    return conv_backward(cluster, x, w, np.asarray(g, np.float32))
+    return conv_backward(cluster, x, w, g)
 
 
 def conv_forward_chain(
@@ -228,10 +234,9 @@ def conv_forward_chain(
     if between is None:
         between = [None] * len(layer_weights)
     assert len(between) == len(layer_weights)
-    x = np.asarray(x, np.float32)
+    x = sides.float32(x)
     batch = x.shape[0]
-    n = cluster._n_micro(batch)
-    parts: List[np.ndarray] = np.array_split(x, n, axis=0) if n > 1 else [x]
+    parts: List[np.ndarray] = _microbatches(cluster, x)
     for w, f in zip(layer_weights, between):
         # plan from the FULL batch shape: one split per layer, every
         # microbatch rides it (and the slave's cached kernel)
@@ -251,7 +256,7 @@ def conv_forward_chain(
         outs.append(cluster._master_comp(f, y) if f else y)
         parts = outs
     cluster._update_comp_duty()
-    return np.concatenate(parts, axis=0) if len(parts) > 1 else parts[0]
+    return sides.concat(parts, axis=0) if len(parts) > 1 else parts[0]
 
 
 def conv_train_chain(
@@ -290,9 +295,8 @@ def conv_train_chain(
     assert len(between) == L
     # split along the SAME slices drivers use for labels/targets, by
     # construction (head(z, i) pairs activations with slice i)
-    x = np.asarray(x, np.float32)
-    slices = microbatch_slices(cluster, x.shape[0])
-    parts: List[np.ndarray] = [x[sl] for sl in slices]
+    x = sides.float32(x)
+    parts: List[np.ndarray] = _microbatches(cluster, x)
     n = len(parts)
 
     # plans fixed for the whole step: fwd and bwd must split every
@@ -346,7 +350,7 @@ def conv_train_chain(
         cur: List[Pending] = []
         for i in range(n):
             xi = parts[i] if k == 0 else fwd_finish(k - 1, i, pend[i])
-            xi = np.asarray(xi, np.float32)
+            xi = sides.float32(xi)
             stash_x[k][i] = xi
             cur.append(
                 cluster._scatter_conv_planned(
@@ -363,7 +367,7 @@ def conv_train_chain(
         z = fwd_finish(L - 1, i, pend[i])
         with tracing.span("cluster.head", cluster.timing, "comp_s"):
             head_aux[i], gz = head(z, i)
-        gy = bwd_through(L - 1, i, np.asarray(gz, np.float32))
+        gy = bwd_through(L - 1, i, sides.float32(gz))
         cur.append(
             cluster._scatter_bwd_planned(
                 stash_x[L - 1][i], plans[L - 1], gy, send_weights=(i == 0)
@@ -402,7 +406,7 @@ def conv_train_chain(
     return TrainStepResult(
         head_aux=head_aux,
         dw=[d for d in dw],
-        dx=np.concatenate(dxs, axis=0) if n > 1 else dxs[0],
+        dx=sides.concat(dxs, axis=0) if n > 1 else dxs[0],
     )
 
 
@@ -455,7 +459,7 @@ class ServeChain:
             between = [None] * len(layer_weights)
         assert len(layer_weights) >= 1 and len(between) == len(layer_weights)
         self.cluster = cluster
-        self.weights = [np.asarray(w, np.float32) for w in layer_weights]
+        self.weights = [sides.float32(w) for w in layer_weights]
         self.between = list(between)
         self._tail: Optional[Pending] = None  # previous batch's final layer
 
@@ -487,7 +491,7 @@ class ServeChain:
                 errors — those drain on the survivors).
         """
         cluster, weights, between = self.cluster, self.weights, self.between
-        x = np.asarray(x, np.float32)
+        x = sides.float32(x)
         # batch k+1's first scatter goes out BEFORE batch k's last
         # gather: its bytes ride the links while the slaves still
         # compute batch k's final layer

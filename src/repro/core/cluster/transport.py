@@ -46,7 +46,11 @@ measures the ring instead of the socket.
 
 Every transport routes messages through a per-link ``codec.WireCodec``
 (the compressor stack), and counts ``codec.wire_nbytes`` of the ENCODED
-message — identical canonical accounting everywhere.
+message — identical canonical accounting everywhere.  The in-process
+link carries ``jax.Array`` payloads as they are (a shard that stays on
+the chip); tcp and shm bring them to the host first
+(``codec.host_arrays``), since only host bytes can be pickled or parked
+in a ring.
 
 Liveness: ``SlaveLost`` is the transport's "this link's slave is gone"
 signal — EOF/reset on the socket, a failed writer, or (with
@@ -493,7 +497,7 @@ class TCPTransport(Transport):
         lost or the writer already failed."""
         self._check_lost()
         self._check_writer()
-        obj = self._codec.encode_down(obj)
+        obj = self._codec.encode_down(codec.host_arrays(obj))
         self.bytes_to_slave += codec.wire_nbytes(obj)
         self._enqueue(obj)
 
@@ -693,7 +697,7 @@ class TCPSlaveEndpoint:
     def send(self, obj) -> None:
         """Encode + frame ``obj`` to the master, serialized under the
         send lock (results and heartbeats share the socket)."""
-        obj = self._codec.encode_up(obj)
+        obj = self._codec.encode_up(codec.host_arrays(obj))
         payload = _dumps(obj)
         with self._send_lock:
             # reprolint: allow=blocking-under-lock -- the lock EXISTS to serialize the blocking send: heartbeats and results share one socket, and an interleaved partial frame corrupts the wire
@@ -1032,7 +1036,7 @@ class ShmSlaveEndpoint(TCPSlaveEndpoint):
         """Encode, park arrays in the tx ring, frame the skeleton —
         all under the send lock (the ring is single-producer and the
         socket must carry whole frames)."""
-        obj = self._codec.encode_up(obj)
+        obj = self._codec.encode_up(codec.host_arrays(obj))
         with self._send_lock:
             if self._tx_ring is not None:
                 # reprolint: allow=blocking-under-lock -- single-producer ring + shared socket: both the ring write and the frame send MUST serialize under this lock or frames interleave

@@ -12,11 +12,13 @@ exchange) or pick the cheaper axis per layer (``partition="auto"``).
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.configs.base import CNNConfig
 from repro.layers.conv import apply_conv, conv_axes, init_conv, max_pool
@@ -113,6 +115,13 @@ def make_cluster_train_step(cluster, cfg: CNNConfig, *, lr: float = 0.05):
     ``make_distributed_conv`` it is safe with any master backend, and the
     cluster's comp-aware partitioner sees the master's real non-conv duty.
 
+    The step is device-resident: images and labels go up once, and the
+    activations, gradients, kernels and every SGD update stay on JAX's
+    default device.  Members on the chip (``xla``, ``pallas``) compute
+    there and the master assembles their shards there; only a host
+    member's (``numpy``) inputs come down and its outputs go up, and the
+    loss and accuracy come down once, at the step's end.
+
     The cluster's partition axis is transparent here: with
     ``partition="spatial"`` (or ``"auto"``) the chain ships height strips
     + halos instead of full activations and seam-sums the dX halos on the
@@ -140,11 +149,12 @@ def make_cluster_train_step(cluster, cfg: CNNConfig, *, lr: float = 0.05):
 
     # jit the master-only stages (cached per microbatch shape); the
     # backward halves rematerialize the forward instead of holding jax
-    # residuals across the pipeline
+    # residuals across the pipeline.  Python numbers enter the jits as
+    # compile-time constants, never as arguments to copy up.
     _stage_fwd = jax.jit(_stage)
     _stage_bwd = jax.jit(lambda y, b, gz: jax.vjp(_stage, y, b)[1](gz))
 
-    @jax.jit
+    @functools.partial(jax.jit, static_argnums=3)
     def _head_both(z, fc, labels, denom):
         (loss, correct), vjp = jax.vjp(
             lambda zz, f: _head_sums(zz, f, labels, denom), z, fc
@@ -152,15 +162,23 @@ def make_cluster_train_step(cluster, cfg: CNNConfig, *, lr: float = 0.05):
         gz, gfc = vjp((jnp.ones((), jnp.float32), jnp.zeros((), jnp.float32)))
         return loss, correct, gz, gfc
 
-    warmed: set = set()  # microbatch sizes whose jits are compiled
+    @jax.jit
+    def _sgd(params, grads):
+        """Plain SGD at fp32 on the device, over any pytree."""
+        return jax.tree.map(lambda p, g: p - lr * g, params, grads)
 
-    def _warm(mb, params):
+    # each microbatch's (loss, correct), stacked to come down in one copy
+    _stack = jax.jit(lambda head_aux: jnp.asarray(head_aux, jnp.float32))
+
+    warmed: set = set()  # (microbatch, batch) sizes whose jits are compiled
+
+    def _warm(mb, batch, params):
         """Compile the master-only jits for this microbatch size OUTSIDE
         the pipeline: one-time compilation must not pollute the cluster's
         measured non-conv duty (it would strip the master's conv share)."""
-        if mb in warmed:
+        if (mb, batch) in warmed:
             return
-        warmed.add(mb)
+        warmed.add((mb, batch))
         h1 = cfg.image_size
         h2, h3 = h1 // cfg.pool_stride, h1 // cfg.pool_stride ** 2
         for h, c, b in ((h1, cfg.c1_kernels, params["conv1"]["bias"]),
@@ -172,76 +190,66 @@ def make_cluster_train_step(cluster, cfg: CNNConfig, *, lr: float = 0.05):
             _stage_bwd(y, b, gz)
         _head_both(
             jnp.zeros((mb, h3, h3, cfg.c2_kernels), jnp.float32), params["fc"],
-            jnp.zeros((mb,), jnp.int32), jnp.float32(1.0),
+            jnp.zeros((mb,), jnp.int32), batch,
         )
 
     def step(params, images, labels):
-        images = to_host(images, np.float32)
-        labels = to_host(labels)
+        params = jax.tree.map(to_device, params)
+        images = to_device(images, np.float32)
+        labels = to_device(labels)
         batch = images.shape[0]
         slices = cluster.microbatch_slices(batch)
         for sl in slices:
-            _warm(sl.stop - sl.start, params)
+            _warm(sl.stop - sl.start, batch, params)
 
         db = {0: None, 1: None}       # conv bias grads, summed over microbatches
         fc_grad = [None]              # fc param grads (a pytree), ditto
 
         def make_between(k, bias):
             def f(y):
-                y = to_device(y)
                 z = _stage_fwd(y, bias)
 
                 def pull(gz):
-                    gy, gb = _stage_bwd(y, bias, to_device(gz, jnp.float32))
-                    gb = to_host(gb)
+                    gy, gb = _stage_bwd(y, bias, gz)
                     db[k] = gb if db[k] is None else db[k] + gb
-                    return to_host(gy, np.float32)
+                    return gy
 
-                return to_host(z, np.float32), pull
+                return z, pull
             return f
 
         def head(z, i):
-            lbl = to_device(labels[slices[i]])
-            loss_i, correct_i, gz, gfc = _head_both(
-                to_device(z), params["fc"], lbl, jnp.float32(batch)
-            )
+            lbl = lax.slice_in_dim(labels, slices[i].start, slices[i].stop)
+            loss_i, correct_i, gz, gfc = _head_both(z, params["fc"], lbl, batch)
             fc_grad[0] = gfc if fc_grad[0] is None else jax.tree.map(
                 jnp.add, fc_grad[0], gfc
             )
-            return ((float(to_host(loss_i)), float(to_host(correct_i))),
-                    to_host(gz, np.float32))
-
-        def update(w, dw):
-            with span("cnn.update"):
-                return w - lr * dw
+            return (loss_i, correct_i), gz
 
         between = [
             make_between(0, params["conv1"]["bias"]),
             make_between(1, params["conv2"]["bias"]),
         ]
-        with span("cnn.update"):
-            kernels = [
-                to_host(params["conv1"]["kernel"], np.float32),
-                to_host(params["conv2"]["kernel"], np.float32),
-            ]
-        new_kernels, res = cluster.conv_train_step(
+
+        def update(w, dw):
+            with span("cnn.update"):
+                return _sgd(w, dw)
+
+        kernels = [params["conv1"]["kernel"], params["conv2"]["kernel"]]
+        (k1, k2), res = cluster.conv_train_step(
             images, kernels, between, head, update=update,
         )
-
-        loss = float(sum(a[0] for a in res.head_aux))
-        acc = float(sum(a[1] for a in res.head_aux)) / batch
         with span("cnn.update"):
-            new_params = {
-                "conv1": {
-                    "kernel": to_device(new_kernels[0]),
-                    "bias": params["conv1"]["bias"] - to_device(lr * db[0]),
-                },
-                "conv2": {
-                    "kernel": to_device(new_kernels[1]),
-                    "bias": params["conv2"]["bias"] - to_device(lr * db[1]),
-                },
-                "fc": jax.tree.map(lambda p, g: p - lr * g, params["fc"], fc_grad[0]),
-            }
-        return new_params, loss, acc
+            rest = _sgd(
+                (params["conv1"]["bias"], params["conv2"]["bias"], params["fc"]),
+                (db[0], db[1], fc_grad[0]),
+            )
+        new_params = {
+            "conv1": {"kernel": k1, "bias": rest[0]},
+            "conv2": {"kernel": k2, "bias": rest[1]},
+            "fc": rest[2],
+        }
+        aux = to_host(_stack(res.head_aux))
+        loss = sum(float(v) for v in aux[:, 0])
+        return new_params, loss, sum(float(v) for v in aux[:, 1]) / batch
 
     return step

@@ -50,6 +50,39 @@ def test_pipelined_backward_matches_reference(pipelined):
     np.testing.assert_allclose(dw, np.asarray(dw_want), atol=1e-4)
 
 
+@pytest.mark.parametrize("entry", ["conv_forward", "conv_backward", "serve_chain"])
+def test_numpy_callers_get_numpy_back_from_chip_members(entry):
+    """Members that keep their shards on the device (an xla master and a
+    pallas member): the gathers assemble there, and a numpy caller of
+    the public edge still gets numpy back, equal to the reference."""
+    from repro import tracing
+    from repro.core.cluster.scheduler import ServeChain
+
+    x, w, g = _data(b=3, cout=6, seed=3)
+    _, pullback = jax.vjp(_ref_conv, jnp.asarray(x), jnp.asarray(w))
+    c = HeteroCluster([1.0, 1.0], ["xla", "pallas:interpret"], pipeline=True,
+                      microbatches=2)
+    try:
+        c.probe_times = [1.0, 1.0]
+        before = tracing.counters()
+        if entry == "conv_forward":
+            got, want = [c.conv_forward(x, w)], [_ref_conv(x, w)]
+        elif entry == "conv_backward":
+            got, want = list(c.conv_backward(x, w, g)), list(pullback(jnp.asarray(g)))
+        else:
+            chain = ServeChain(c, [w])
+            assert chain.push(x) is None
+            got, want = [chain.flush()], [_ref_conv(x, w)]
+        after = tracing.counters()
+    finally:
+        c.shutdown()
+    for a, b in zip(got, want):
+        assert type(a) is np.ndarray
+        np.testing.assert_allclose(a, np.asarray(b), atol=1e-4)
+    assert after["device_assembles"] > before["device_assembles"]
+    assert after["host_assembles"] == before["host_assembles"]
+
+
 def test_single_image_degenerates_to_barrier(pipelined):
     """batch < microbatches: no empty microbatches, same numerics."""
     x, w, _ = _data(b=1, seed=2)

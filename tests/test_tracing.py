@@ -1,7 +1,7 @@
 """The program's spans and host<->device copy counters (``repro.tracing``)
-on the CPU: a tiny cluster train step (xla master, numpy member, inproc,
-two microbatches) under the profiler, its trace reduced by
-``chip_bench/spans.py``."""
+on the CPU: a tiny cluster train step (xla master, a numpy or pallas
+member, inproc, two microbatches) under the profiler, its trace reduced
+by ``chip_bench/spans.py``."""
 import os
 import sys
 import threading
@@ -36,8 +36,8 @@ STEP_SPANS = ("cluster.scatter", "cluster.gather", "cluster.master_conv",
               "host.to_device", "host.to_host", "device.wait")
 
 
-def _cluster(partition="kernel"):
-    c = HeteroCluster([1.0, 1.0], ["xla", "numpy"], pipeline=True, microbatches=MICRO,
+def _cluster(partition="kernel", member="numpy"):
+    c = HeteroCluster([1.0, 1.0], ["xla", member], pipeline=True, microbatches=MICRO,
                       partition=partition, comp_aware=False, transport="inproc")
     c.probe(image_size=CFG.image_size, in_channels=CFG.image_channels,
             kernel_size=CFG.kernel_size, num_kernels=CFG.c1_kernels, batch=BATCH)
@@ -121,41 +121,72 @@ def test_step_spans_sit_on_their_threads_and_feed_layer_timing(tmp_path):
         assert abs(kept[name] - _span_s(red, (name,))) < 1e-3, name
 
 
-def reckoned_bytes(cfg, batch, micro, s0, s1):
+def reckoned_bytes(cfg, batch, micro, s0, s1, host_member=True):
     """Bytes a step of ``make_cluster_train_step`` copies between host
     and device with an xla master holding ``s0``/``s1`` kernels of
-    conv1/conv2 and a numpy member the rest: ``(h2d, d2h)``."""
+    conv1/conv2 and one member the rest: ``(h2d, d2h)``.  Images and
+    labels go up once, the microbatches' losses and correct counts come
+    down once; everything else stays on the device, except what a host
+    member (``numpy``) reads and returns: its inputs come down, its
+    outputs go up to be assembled there."""
     mb, k, cin, c1, c2 = batch // micro, cfg.kernel_size, cfg.image_channels, \
         cfg.c1_kernels, cfg.c2_kernels
     h1 = cfg.image_size
-    h2, h3 = h1 // 2, h1 // 4
+    h2 = h1 // 2
+    up = batch * h1 * h1 * cin * 4 + batch * 4   # images, int32 labels
+    down = micro * 2 * 4                         # (loss, correct) per microbatch
+    if not host_member:
+        return up, down
+    n0, n1 = c1 - s0, c2 - s1                    # the member's kernels
     x0, x1 = mb * h1 * h1 * cin, mb * h2 * h2 * c1        # conv inputs
-    y0, y1 = mb * h1 * h1 * c1, mb * h2 * h2 * c2         # conv outputs
-    z1 = mb * h3 * h3 * c2                                # conv2 stage output
-    w0, w1 = k * k * cin * s0, k * k * c1 * s1            # the master's shards
-    m0, m1 = mb * h1 * h1 * s0, mb * h2 * h2 * s1         # its outputs
-    up = (x0 + w0) + (x1 + w1)              # master conv fwd
-    up += (x1 + w1 + m1) + (x0 + w0 + m0)   # master conv bwd: x, w, g
-    up += y0 + y1                           # stage fwd inputs
-    up += mb + z1                           # head: labels, activations
-    up += z1 + x1                           # stage bwd: upstream grads
-    down = m0 + m1                          # master conv fwd outputs
-    down += (x1 + w1) + (x0 + w0)           # master conv bwd: dx, dw
-    down += x1 + z1                         # stage fwd outputs
-    down += 1 + 1 + z1                      # head: loss, correct, grad
-    down += (c2 + y1) + (c1 + y0)           # stage bwd: bias grad, grad
-    kernels = k * k * cin * c1 + k * k * c1 * c2
-    return 4 * (micro * up + kernels + c1 + c2), 4 * (micro * down + kernels)
+    y0, y1 = mb * h1 * h1 * n0, mb * h2 * h2 * n1         # its outputs
+    w0, w1 = k * k * cin * n0, k * k * c1 * n1            # its shards
+    down_mb = x0 + x1                        # fwd inputs
+    down_mb += (x1 + y1) + (x0 + y0)         # bwd inputs: x, grad slice
+    up_mb = y0 + y1                          # fwd outputs
+    up_mb += (x1 + w1) + (x0 + w0)           # bwd outputs: full dx, dw shard
+    # its shards come down once a step: the weight cache holds the host copy
+    return up + 4 * micro * up_mb, down + 4 * (micro * down_mb + w0 + w1)
 
 
-def test_host_copy_bytes_are_the_hand_reckoned_ones(tmp_path):
-    c = _cluster()
+@pytest.mark.parametrize("member", ["numpy", "pallas:interpret"])
+def test_host_copy_bytes_are_the_hand_reckoned_ones(tmp_path, member):
+    c = _cluster(member=member)
     try:
         assert [list(c.shares_for(n)) for n in (4, 6)] == [[2, 2], [3, 3]]
+        before = tracing.counters()
         _red, _found, moved, _traced = _traced_step(tmp_path, c)
     finally:
         c.shutdown()
-    assert (moved["h2d_bytes"], moved["d2h_bytes"]) == reckoned_bytes(CFG, BATCH, MICRO, 2, 3)
+    host = member == "numpy"
+    assert (moved["h2d_bytes"], moved["d2h_bytes"]) == reckoned_bytes(
+        CFG, BATCH, MICRO, 2, 3, host_member=host)
+    # every gather of the traced step (2 layers x 2 microbatches, forward
+    # and backward) assembled on the device; none of either step on the host
+    assert moved["device_assembles"] == 8
+    assert tracing.counters()["host_assembles"] == before["host_assembles"]
+
+
+@pytest.mark.parametrize("member", ["numpy", "pallas:interpret"])
+def test_step_uploads_nothing_behind_the_counters(member):
+    """A warm step with every host-to-device transfer that does not go
+    through ``repro.tracing`` refused (on every thread): a jit call given
+    numpy, an eager op given a Python number or an index would raise.
+    Off a chip the device's arrays are host memory, so the other
+    direction is checked on the chip (PERF.md)."""
+    c = _cluster(member=member)
+    try:
+        step = make_cluster_train_step(c, CFG, lr=0.01)
+        params, images, labels = _inputs()
+        params, loss0, _ = step(params, images, labels)
+        jax.config.update("jax_transfer_guard_host_to_device", "disallow")
+        try:
+            _params, loss1, _ = step(params, images, labels)
+        finally:
+            jax.config.update("jax_transfer_guard_host_to_device", "allow")
+    finally:
+        c.shutdown()
+    assert np.isfinite(loss0) and np.isfinite(loss1)
 
 
 @pytest.mark.parametrize("partition, ops", [

@@ -82,37 +82,95 @@ def test_train_chain_matches_single_device_vjp(backends):
         c.shutdown()
 
 
-def test_cluster_train_step_matches_sgd():
+# (slowdowns, backends) of the clusters the train step is checked on: the
+# seed's all-numpy cluster, and the xla master with a host member or a
+# member on the chip (pallas, interpreted off a TPU)
+TRAIN_STEP_MEMBERS = {
+    "numpy": ([1.0, 1.5, 2.0], None),
+    "xla-numpy": ([1.0, 1.0], ["xla", "numpy"]),
+    "xla-pallas": ([1.0, 1.0], ["xla", "pallas:interpret"]),
+}
+
+
+def _sgd_reference(cfg, params, imgs, labels, lr):
+    """Single-device loss and SGD step (jitted: one compile, not one per op)."""
+    (loss_ref, _), grads = jax.jit(jax.value_and_grad(
+        lambda p: cnn_loss(p, imgs, labels, cfg=cfg), has_aux=True
+    ))(params)
+    return loss_ref, jax.tree.map(lambda p, g: p - lr * g, params, grads)
+
+
+def _assert_params_close(ref_new, new_params):
+    flat_ref, _ = jax.tree_util.tree_flatten_with_path(ref_new)
+    flat_new, _ = jax.tree_util.tree_flatten_with_path(new_params)
+    for (pa, a), (_pb, b) in zip(
+        sorted(flat_ref, key=lambda kv: str(kv[0])),
+        sorted(flat_new, key=lambda kv: str(kv[0])),
+    ):
+        np.testing.assert_allclose(
+            np.asarray(b), np.asarray(a), atol=1e-4, err_msg=str(pa)
+        )
+
+
+@pytest.mark.parametrize("partition", ["kernel", "batch", "spatial"])
+@pytest.mark.parametrize("members", list(TRAIN_STEP_MEMBERS))
+def test_cluster_train_step_matches_sgd(members, partition):
     """The models/cnn.py driver: one distributed step == loss/grads/SGD of
-    the single-device reference, end to end (conv, bias, LRN, pool, fc)."""
+    the single-device reference, end to end (conv, bias, LRN, pool, fc),
+    on every axis and whether the members compute on the host or keep
+    their shards on the device."""
     cfg = make_cnn_config(6, 10)
     params = init_cnn(jax.random.key(0), cfg)
     imgs = jax.random.normal(jax.random.key(1), (5, 32, 32, 3))
     labels = jnp.array([0, 1, 2, 3, 4])
     lr = 0.05
+    loss_ref, ref_new = _sgd_reference(cfg, params, imgs, labels, lr)
 
-    (loss_ref, _), grads = jax.value_and_grad(
-        lambda p: cnn_loss(p, imgs, labels, cfg=cfg), has_aux=True
-    )(params)
-    ref_new = jax.tree.map(lambda p, g: p - lr * g, params, grads)
-
-    c = HeteroCluster([1.0, 1.5, 2.0], pipeline=True, microbatches=3)
+    slowdowns, backends = TRAIN_STEP_MEMBERS[members]
+    c = HeteroCluster(slowdowns, backends, pipeline=True, microbatches=3,
+                      partition=partition)
     try:
         c.probe(image_size=8, in_channels=3, kernel_size=5, num_kernels=8, batch=2)
         step = make_cluster_train_step(c, cfg, lr=lr)
         new_params, loss, _acc = step(params, imgs, labels)
         assert np.isclose(float(loss_ref), loss, atol=1e-5)
-        flat_ref, _ = jax.tree_util.tree_flatten_with_path(ref_new)
-        flat_new, _ = jax.tree_util.tree_flatten_with_path(new_params)
-        for (pa, a), (_pb, b) in zip(
-            sorted(flat_ref, key=lambda kv: str(kv[0])),
-            sorted(flat_new, key=lambda kv: str(kv[0])),
-        ):
-            np.testing.assert_allclose(
-                np.asarray(b), np.asarray(a), atol=1e-4, err_msg=str(pa)
-            )
+        _assert_params_close(ref_new, new_params)
+        assert all(isinstance(a, jax.Array) for a in jax.tree.leaves(new_params))
         # the chain measured the master's non-conv duty for Eq. 1
         assert 0.0 < c.comp_duty <= 1.0
+    finally:
+        c.shutdown()
+
+
+@pytest.mark.parametrize("member", ["numpy", "pallas:interpret"])
+def test_cluster_train_step_recovers_a_lost_member(member):
+    """A member evicted mid-step: the master recomputes its in-flight
+    shards from the plans (on the device, for an xla master) and the
+    step still equals single-device SGD."""
+    cfg = make_cnn_config(4, 6)
+    params = init_cnn(jax.random.key(2), cfg)
+    imgs = jax.random.normal(jax.random.key(3), (4, 32, 32, 3))
+    labels = jnp.array([1, 3, 5, 7])
+    lr = 0.05
+    loss_ref, ref_new = _sgd_reference(cfg, params, imgs, labels, lr)
+
+    c = HeteroCluster([1.0, 1.0], ["xla", member], pipeline=True, microbatches=2)
+    try:
+        c.probe_times = [1.0, 1.0]
+        gather = c.gather_conv
+
+        def gather_then_evict(p):
+            if c.slave_ids:
+                c.evict(c.slave_ids[0])
+            return gather(p)
+
+        c.gather_conv = gather_then_evict
+        new_params, loss, _acc = make_cluster_train_step(c, cfg, lr=lr)(
+            params, imgs, labels
+        )
+        assert c.timing.recompute_s > 0.0 and not c.slave_ids
+        assert np.isclose(float(loss_ref), loss, atol=1e-5)
+        _assert_params_close(ref_new, new_params)
     finally:
         c.shutdown()
 

@@ -7,11 +7,13 @@ and FIFO order, canonical nbytes accounting (identical numbers on every
 wire, with and without each codec stage), slave-error propagation, and
 — subprocess wires — measured link bandwidth feeding the comm-aware
 partitioner, subprocess slave numerics vs the single-device VJP on
-every partition axis, and orderly subprocess shutdown on cluster close
-and after a master-side protocol exception.  Shm additionally proves
+every partition axis, device-array payloads crossing a real wire or a
+narrowing codec exactly as numpy would, and orderly subprocess shutdown
+on cluster close and after a master-side protocol exception.  Shm additionally proves
 segment hygiene (nothing leaks into /dev/shm) and the inline fallback
 for arrays larger than the ring.
 """
+import functools
 import threading
 
 import numpy as np
@@ -398,6 +400,69 @@ def test_subprocess_train_chain_matches_single_device_vjp(partition, kind):
         np.testing.assert_allclose(res.dw[1], dw2_want, rtol=1e-4, atol=1e-3)
     finally:
         c.shutdown()
+
+
+@pytest.mark.parametrize("kind, member, spec", [
+    ("tcp", "numpy", None),
+    ("shm", "numpy", None),
+    ("inproc", "xla", "acts=fp16,grads=int8"),
+    ("tcp", "numpy", "acts=fp16,grads=int8"),
+])
+def test_device_inputs_cross_the_wire_as_numpy_does(kind, member, spec):
+    """The train chain given device arrays, as the device-resident train
+    step gives it, over a real wire or a narrowing codec: what crosses
+    comes to the host first, the link byte counters read exactly what a
+    numpy-input run of the same work reads, the results match that run,
+    and on an fp32 wire they match the single-device VJP too."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.tracing import to_device
+
+    rng = np.random.default_rng(7)
+    x = rng.normal(size=(5, 8, 8, 3)).astype(np.float32)
+    w1 = rng.normal(size=(3, 3, 3, 6)).astype(np.float32)
+    w2 = rng.normal(size=(3, 3, 6, 9)).astype(np.float32)
+    g = rng.normal(size=(5, 8, 8, 9)).astype(np.float32)
+
+    c = HeteroCluster([1.0, 1.0], ["xla", member], transport=kind,
+                      wire_codec=spec, pipeline=True, microbatches=3,
+                      comp_aware=False)  # both runs split alike
+    try:
+        c.probe_times = [1.0, 1.0]
+        slices = c.microbatch_slices(x.shape[0])
+
+        def run(xp, put):
+            def between(y):
+                mask = (y > 0).astype(np.float32)
+                return xp.maximum(y, 0.0), lambda gz: gz * mask
+
+            gs = [put(g[sl]) for sl in slices]
+            c.reset_stats()
+            res = c.conv_train_chain(
+                put(x), [put(w1), put(w2)], [between, None], lambda z, i: (None, gs[i])
+            )
+            return res, [(s.bytes_to_slave, s.bytes_to_master) for s in c.sockets]
+
+        host, host_bytes = run(np, np.asarray)
+        dev, dev_bytes = run(jnp, to_device)
+    finally:
+        c.shutdown()
+    assert dev_bytes == host_bytes and host_bytes[0][0] > 0
+    assert isinstance(dev.dx, jax.Array) and isinstance(host.dx, np.ndarray)
+    for a, b in zip([dev.dx] + dev.dw, [host.dx] + host.dw):
+        np.testing.assert_allclose(np.asarray(a), b, rtol=1e-6, atol=1e-6)
+    if spec is None:
+        def f(x_, w1_, w2_):
+            conv = functools.partial(
+                jax.lax.conv_general_dilated, window_strides=(1, 1), padding="SAME",
+                dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            )
+            return jnp.sum(conv(jax.nn.relu(conv(x_, w1_)), w2_) * g)
+
+        want = jax.grad(f, argnums=(0, 1, 2))(x, w1, w2)
+        for a, b in zip([dev.dx] + dev.dw, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-3)
 
 
 def test_spawned_slave_is_a_host_cpu_member(monkeypatch):
