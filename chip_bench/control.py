@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
 """The control and the planted faults that the comparison of
-``reference.py`` has to fail, and the readings that set its limits.
+``reference.py`` has to fail, and the readings that set its limits, for
+the CNN's cells (``archs/cnn.py``).
 
 The configuration states fp32 at matmul precision "highest".  The
 control is the reference one step down, at "high": every product of the
@@ -47,6 +48,7 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
         sys.path.insert(0, _p)
 
 from chip_bench import reference  # noqa: E402
+from chip_bench.archs import cnn  # noqa: E402
 
 
 def _split(a):
@@ -87,11 +89,11 @@ def three_pass(f):
 
 
 def _highest(f):
-    return lambda a, b: f(a, b, reference._precision("highest"))
+    return lambda a, b: f(a, b, reference.precision_of("highest"))
 
 
-_HIGH_CONV = three_pass(_highest(reference.plain_conv))
-_HIGH_DOT = three_pass(_highest(reference.plain_dot))
+_HIGH_CONV = three_pass(_highest(cnn.plain_conv))
+_HIGH_DOT = three_pass(_highest(cnn.plain_dot))
 
 
 def high_conv(x, w, _precision):
@@ -106,7 +108,7 @@ def high_dot(x, w, _precision):
 
 def control_step(cfg, lr):
     """The reference step at "high"."""
-    return reference.reference_step(cfg, lr, conv=high_conv, dot=high_dot)
+    return cnn.reference_step(cfg, lr, conv=high_conv, dot=high_dot)
 
 
 def exchange_conv(members: int):
@@ -119,7 +121,7 @@ def exchange_conv(members: int):
 def _first_shard_conv(x, w, precision, members):
     import jax.numpy as jnp
 
-    y = reference.plain_conv(x, w, precision)
+    y = cnn.plain_conv(x, w, precision)
     keep = -(-w.shape[-1] // members)
     mask = jnp.arange(w.shape[-1]) < keep
     return y * mask
@@ -141,11 +143,11 @@ class ReferencePath:
         if kind == "control":
             self._step = control_step(cfg, lr)
         elif kind == "no_exchange":
-            self._step = reference.reference_step(cfg, lr, conv=exchange_conv(members))
+            self._step = cnn.reference_step(cfg, lr, conv=exchange_conv(members))
         elif kind == "high":
-            self._step = reference.reference_step(cfg, lr, kind)
+            self._step = cnn.reference_step(cfg, lr, kind)
         else:
-            self._step = reference.reference_step(cfg, lr)
+            self._step = cnn.reference_step(cfg, lr)
 
     def place(self, params, images, labels):
         return params, [(images[i], labels[i]) for i in range(images.shape[0])]

@@ -1,8 +1,8 @@
 """Least time of the conv work the chips executed in the traced window
 over the summed device time of the conv events in its trace.  The work
-is counted from shapes (``flops.conv_shard_work``) for each chip's
-kernel widths; least time is the larger of operations over peak and
-bytes over HBM bandwidth.  Nothing to read without conv events."""
+is counted from shapes (the architecture's ``shard_work``) for each
+chip's kernel widths; least time is the larger of operations over peak
+and bytes over HBM bandwidth.  Nothing to read without conv events."""
 
 
 def read(m):
@@ -13,5 +13,5 @@ def read(m):
         return None
     p = m.peaks()
     least = sum(max(f / p["flops_per_s"], b / p["hbm_bytes_per_s"])
-                for f, b in m.conv_work.values())
+                for f, b in m.shard_work.values())
     return 100.0 * least / conv_s

@@ -1,13 +1,10 @@
-"""The plain reference: the paper's CNN training step in straightforward
-single-device jax, written from the configuration file alone.  It
+"""The plain reference's run and the comparison that decides ``correct``.
+
+Each architecture (``chip_bench/archs/<arch>.py``) writes its training
+step in straightforward single-device jax from the configuration file
+alone, with plain SGD on every parameter (``reference_step``); it
 imports nothing of the system under test and is given nothing it made.
-
-    conv(5x5, C1) + b -> ReLU -> LRN -> maxpool/2 ->
-    conv(5x5, C2) + b -> ReLU -> LRN -> maxpool/2 -> fc -> softmax loss
-
-then plain SGD on every parameter.  Every convolution and matrix product
-runs at the precision the configuration states ("highest": fp32), so
-the program and this step differ only in summation order.
+``run_reference`` drives that step over the checked batches.
 
 The comparison follows the first three steps of a run.  Its numbers:
 
@@ -35,104 +32,26 @@ leaf's move by round-off alone and are left out of ``grad_gap`` and
 """
 from __future__ import annotations
 
-import functools
-
 import numpy as np
 
 NEGLIGIBLE = 1e-3  # a leaf whose reference gradient norm is below this
 #                    share of the median leaf's is left out
 
 
-def _precision(name: str):
+def precision_of(name: str):
+    """``lax.Precision`` of a configuration's ``matmul_precision``."""
     from jax import lax
 
     return {"highest": lax.Precision.HIGHEST, "high": lax.Precision.HIGH}[name]
 
 
-def plain_conv(x, w, precision):
-    """NHWC x HWIO, stride 1, SAME."""
-    from jax import lax
-
-    return lax.conv_general_dilated(
-        x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
-        precision=precision,
-    )
-
-
-def lrn(x, size: int, alpha: float, beta: float, k: float):
-    """Cross-channel local response normalisation:
-    x / (k + alpha * sum of x^2 over the ``size`` channels around)^beta."""
-    import jax.numpy as jnp
-
-    half = size // 2
-    sq = jnp.pad(jnp.square(x), [(0, 0)] * 3 + [(half, size - 1 - half)])
-    c = x.shape[-1]
-    window = sum(sq[..., i:i + c] for i in range(size))
-    return x / jnp.power(k + alpha * window, beta)
-
-
-def maxpool(x, s: int):
-    """``s`` x ``s`` windows, stride ``s``, no padding."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    return lax.reduce_window(x, -jnp.inf, lax.max, (1, s, s, 1), (1, s, s, 1), "VALID")
-
-
-def plain_dot(x, w, precision):
-    import jax.numpy as jnp
-
-    return jnp.dot(x, w, precision=precision)
-
-
-def loss_fn(params, images, labels, lrn_args, pool: int, precision: str = "highest",
-            conv=plain_conv, dot=plain_dot):
-    """Mean softmax cross-entropy of the CNN; ``lrn_args`` is (size,
-    alpha, beta, k); ``conv(x, w, precision)`` and ``dot(x, w,
-    precision)`` compute the convolutions and the fc product (replaced
-    only by the control and the planted faults)."""
-    import jax
-    import jax.numpy as jnp
-
-    prec = _precision(precision)
-    x = images
-    for layer in ("conv1", "conv2"):
-        x = conv(x, params[layer]["kernel"], prec) + params[layer]["bias"]
-        x = maxpool(lrn(jax.nn.relu(x), *lrn_args), pool)
-    x = x.reshape(x.shape[0], -1)
-    logits = dot(x, params["fc"]["kernel"], prec) + params["fc"]["bias"]
-    logp = jax.nn.log_softmax(logits)
-    return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
-
-
-@functools.lru_cache(maxsize=16)
-def _jitted_step(lrn_args, pool: int, lr: float, precision: str, conv, dot):
-    import jax
-
-    def step(params, images, labels):
-        loss, grads = jax.value_and_grad(loss_fn)(
-            params, images, labels, lrn_args, pool, precision, conv, dot)
-        return jax.tree.map(lambda p, g: p - lr * g, params, grads), loss
-
-    return jax.jit(step)
-
-
-def reference_step(cfg: dict, lr: float, precision: str = "highest", conv=plain_conv,
-                   dot=plain_dot):
-    """``step(params, images, labels) -> (new_params, loss)``, jitted."""
-    n = cfg["lrn"]
-    lrn_args = (int(n["size"]), float(n["alpha"]), float(n["beta"]), float(n["k"]))
-    return _jitted_step(lrn_args, int(cfg["pool_stride"]), float(lr), precision, conv, dot)
-
-
-def run_reference(cfg, lr, params, images, labels, steps: int = 3,
-                  precision: str = "highest", conv=plain_conv):
-    """The reference's states and losses over ``steps`` steps from
-    ``params`` on batches ``images[i]``/``labels[i]``: ``(states,
+def run_reference(step, params, images, labels, steps: int = 3):
+    """States and losses of ``step(params, images, labels) -> (params,
+    loss)`` (an architecture's ``reference_step``) over ``steps`` steps
+    from ``params`` on batches ``images[i]``/``labels[i]``: ``(states,
     losses)`` with ``states[i]`` the host copy after step ``i + 1``."""
     import jax
 
-    step = reference_step(cfg, lr, precision, conv)
     states, losses = [], []
     p = params
     for i in range(steps):
@@ -143,12 +62,16 @@ def run_reference(cfg, lr, params, images, labels, steps: int = 3,
     return states, losses
 
 
-def to_host(tree) -> dict:
-    """``{"conv1.kernel": float64 array, ...}`` from a parameter tree."""
-    out = {}
-    for layer, leaves in tree.items():
-        for name, a in leaves.items():
-            out[f"{layer}.{name}"] = np.asarray(a, np.float64)
+def to_host(tree, prefix: str = "", out: dict = None) -> dict:
+    """``{"conv1.kernel": float64 array, ...}`` from a parameter tree of
+    nested dicts of any depth, its keys joined by dots."""
+    out = {} if out is None else out
+    for name, value in tree.items():
+        key = f"{prefix}{name}"
+        if isinstance(value, dict):
+            to_host(value, f"{key}.", out)
+        else:
+            out[key] = np.asarray(value, np.float64)
     return out
 
 

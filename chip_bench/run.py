@@ -7,8 +7,10 @@ Everything is found by name.  The cell is an entry of ``BENCHMARK.json``
 at the root of the checkout; its configuration is the file that entry of
 ``configs`` names; its traffic is ``chip_bench/traffic/<traffic>.json``,
 whose ``path`` names the module ``chip_bench/paths/<path>.py`` that
-builds the system under test; its correctness limits are
-``chip_bench/limits/<cell>.json``; each metric is read by
+builds the system under test; the configuration's ``arch`` names the
+module ``chip_bench/archs/<arch>.py`` that makes the inputs and holds
+the plain reference step and the work counts; the cell's correctness
+limits are ``chip_bench/limits/<cell>.json``; each metric is read by
 ``chip_bench/metrics/<metric>.py``.
 
 A run: weights and a ring of distinct batches from ``--seed``; the
@@ -53,7 +55,7 @@ for p in (os.path.join(ROOT, "src"), ROOT):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from chip_bench import data, reference  # noqa: E402
+from chip_bench import reference  # noqa: E402
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -94,6 +96,7 @@ class Cell:
     traffic: dict
     limits: dict
     metrics: list
+    arch: object  # chip_bench/archs/<cfg["arch"]>.py
     root: str = ROOT  # where BENCHMARK.json is
 
     @classmethod
@@ -107,12 +110,13 @@ class Cell:
         metrics = [m for m in bench["per_layer" if trace else "end_to_end"]
                    if applies(m, name)]
         here = os.path.join(bench_root, "chip_bench")
+        cfg = load_json(bench_root, cfg_entry["file"])
         return cls(
-            name=name, chips=int(w["chips"]),
-            cfg=load_json(bench_root, cfg_entry["file"]),
+            name=name, chips=int(w["chips"]), cfg=cfg,
             traffic=load_json(here, "traffic", f"{w['traffic']}.json"),
             limits=load_json(here, "limits", f"{name}.json"),
-            metrics=metrics, root=bench_root,
+            metrics=metrics, arch=load_module("archs", cfg["arch"], bench_root),
+            root=bench_root,
         )
 
     def path_class(self):
@@ -153,7 +157,7 @@ class Measurement:
     window_s: float
     compiles: int
     counters: dict
-    conv_work: dict  # device id -> (flops, bytes) of the chip's conv work in the window
+    shard_work: dict  # device id -> (flops, bytes) of the chip's kernel work in the window
     trace: Optional[dict] = None
 
     def peaks(self) -> dict:
@@ -174,11 +178,9 @@ def timed_step(path, params, x, y, jax):
     return params, loss
 
 
-def add_work(total: dict, widths, cfg, batch, with_input_dx) -> None:
-    from chip_bench.flops import conv_shard_work
-
+def add_work(total: dict, arch, widths, cfg, batch, with_input_dx) -> None:
     for dev, w, calls in widths:
-        f, b = conv_shard_work(cfg, batch, w, calls, with_input_dx)
+        f, b = arch.shard_work(cfg, batch, w, calls, with_input_dx)
         tf, tb = total.get(dev, (0.0, 0.0))
         total[dev] = (tf + f, tb + b)
 
@@ -191,7 +193,7 @@ def run_cell(cell: Cell, path_cls, devices, seed: int, seconds: float, trace: bo
     tr = cell.traffic
     batch, ring, checked, lr = tr["batch"], tr["ring"], tr["checked_steps"], tr["lr"]
     compiles = CompileCounter()
-    params0, images, labels = data.make_inputs(cell.cfg, batch, ring, seed)
+    params0, images, labels = cell.arch.make_inputs(cell.cfg, batch, ring, seed)
     p0 = reference.to_host(params0)
     path = path_cls(cell.cfg, tr, devices)
     log("inputs made, system built" + (f"; {path.note()}" if hasattr(path, "note") else ""))
@@ -225,7 +227,8 @@ def run_cell(cell: Cell, path_cls, devices, seed: int, seconds: float, trace: bo
         step_s = []
         while True:
             t_step = time.perf_counter()
-            add_work(work, path.step_conv_widths(), cell.cfg, batch, path.with_input_dx)
+            add_work(work, cell.arch, path.step_conv_widths(), cell.cfg, batch,
+                     path.with_input_dx)
             with jax.profiler.StepTraceAnnotation("bench.train", step_num=steps):
                 try:
                     params, loss = timed_step(path, params, *feed[i % ring], jax)
@@ -257,7 +260,8 @@ def run_cell(cell: Cell, path_cls, devices, seed: int, seconds: float, trace: bo
         f"slowest {step_s[slow]:.4f} s (step {slow})")
 
     ref_states, ref_losses = reference.run_reference(
-        cell.cfg, lr, params0, images[:checked], labels[:checked], steps=checked)
+        cell.arch.reference_step(cell.cfg, lr), params0, images[:checked], labels[:checked],
+        steps=checked)
     detail: dict = {}
     checks = reference.compare(
         p0, lr, states, losses, [ref_states[0], ref_states[-1]], ref_losses, detail)
@@ -276,13 +280,15 @@ def run_cell(cell: Cell, path_cls, devices, seed: int, seconds: float, trace: bo
 
             prof = ProfileData.from_file(paths[0])
             summary = trace_reduce.summarize(*trace_reduce.load_events(prof))
+            if summary is not None and summary["collectives"]:
+                log(f"collective ops in the trace: {summary['collectives']}")
         shutil.rmtree(tdir, ignore_errors=True)
         log("trace reduced")
     meas = Measurement(
         cell=cell, device_kind=devices[0].device_kind, setup_s=setup_s, steps=steps,
         samples=steps * batch, window_s=window_s, compiles=compiles_in_window,
         counters={k: counters1[k] - counters0[k] for k in counters1},
-        conv_work=work, trace=summary,
+        shard_work=work, trace=summary,
     )
     return meas, checks, failed, peak
 

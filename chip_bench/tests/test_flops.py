@@ -5,6 +5,7 @@ import os
 import pytest
 
 from chip_bench import flops
+from chip_bench.archs import cnn
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "configs")
 
@@ -29,24 +30,24 @@ def test_conv2_fwd_at_500_1500():
     ("cifar_cnn_50_500", 2 * 7_680_000 + 3 * 320_000_000 + 3 * 640_000),
 ])
 def test_model_flops_per_sample(name, per_sample):
-    assert flops.model_flops_per_sample(cfg(name)) == per_sample
+    assert cnn.model_flops_per_sample(cfg(name)) == per_sample
 
 
 def test_step_flops_at_batch_128():
-    assert flops.model_flops_per_sample(cfg("cifar_cnn_500_1500")) * 128 == pytest.approx(3.707e12, rel=1e-3)
-    assert flops.model_flops_per_sample(cfg("cifar_cnn_50_500")) * 128 == pytest.approx(0.125e12, rel=1e-2)
+    assert cnn.model_flops_per_sample(cfg("cifar_cnn_500_1500")) * 128 == pytest.approx(3.707e12, rel=1e-3)
+    assert cnn.model_flops_per_sample(cfg("cifar_cnn_50_500")) * 128 == pytest.approx(0.125e12, rel=1e-2)
 
 
 def test_shard_work_sums_to_whole():
     c = cfg("cifar_cnn_500_1500")
-    whole = flops.conv_shard_work(c, 128, {"conv1": 500, "conv2": 1500})
-    quarter = flops.conv_shard_work(c, 128, {"conv1": 125, "conv2": 375})
+    whole = cnn.shard_work(c, 128, {"conv1": 500, "conv2": 1500})
+    quarter = cnn.shard_work(c, 128, {"conv1": 125, "conv2": 375})
     assert 4 * quarter[0] == whole[0]
-    per_sample = flops.model_flops_per_sample(c) - 3 * 1_920_000
+    per_sample = cnn.model_flops_per_sample(c) - 3 * 1_920_000
     assert whole[0] == per_sample * 128
-    with_dx = flops.conv_shard_work(c, 128, {"conv1": 500, "conv2": 1500}, with_input_dx=True)
+    with_dx = cnn.shard_work(c, 128, {"conv1": 500, "conv2": 1500}, with_input_dx=True)
     assert with_dx[0] - whole[0] == 76_800_000 * 128
     # four microbatch calls read the weight shard four times
-    four = flops.conv_shard_work(c, 128, {"conv2": 1500}, calls=4)
-    one = flops.conv_shard_work(c, 128, {"conv2": 1500}, calls=1)
+    four = cnn.shard_work(c, 128, {"conv2": 1500}, calls=4)
+    one = cnn.shard_work(c, 128, {"conv2": 1500}, calls=1)
     assert four[0] == one[0] and four[1] - one[1] == 3 * 3 * 18_750_000 * 4

@@ -50,6 +50,63 @@ def test_summarize_synthetic():
     assert s["device_ops"][0] == ["%fusion.2", 80e-9]
 
 
+@pytest.mark.parametrize("name, collective", [
+    ("%all-gather.10", True),
+    ("%all-gather-start.1", True),
+    ("%all-gather-done.1", True),
+    ("%all-reduce", True),
+    ("%all-reduce-start.3", True),
+    ("%reduce-scatter-fusion.2", True),
+    ("%collective-permute-done", True),
+    ("%all-to-all.4", True),
+    ("%fusion.47", False),
+    ("%multiply_multiply_fusion.1", False),
+    ("%select_and_scatter.21", False),
+])
+def test_collective_names(name, collective):
+    assert tr.is_collective(name) == collective
+
+
+def exposed(device):
+    s = tr.summarize(host_steps([(0, 100)]), device)
+    return {d: (v["collective_ns"], v["exposed_collective_ns"]) for d, v in s["devices"].items()}
+
+
+def test_collective_hidden_under_a_conv_is_not_exposed():
+    assert exposed({0: [("%fusion.1", 10, 60, "kOutput"), ("%all-gather.1", 20, 50, "")]}) \
+        == {0: (30, 0)}
+
+
+def test_collective_half_exposed():
+    assert exposed({0: [("%fusion.1", 10, 40, "kOutput"), ("%all-reduce.1", 30, 50, "")]}) \
+        == {0: (20, 10)}
+
+
+def test_async_start_and_done_halves():
+    """The start half is exposed only where it runs past the conv's
+    start; the done half, where the device waits, is exposed; the done
+    halves that overlap count once."""
+    device = {0: [("%all-gather-start.1", 0, 10, ""), ("%fusion.2", 5, 50, "kOutput"),
+                  ("%all-gather-done.1", 50, 70, ""), ("%all-reduce-done", 65, 75, "")]}
+    assert exposed(device) == {0: (10 + 20 + 10, 5 + 25)}
+
+
+def test_exposed_collective_ms_takes_the_worst_device():
+    from chip_bench import run
+
+    device = {0: [("%fusion.1", 0, 40, "kOutput"), ("%all-gather.1", 30, 50, "")],
+              1: [("%fusion.1", 0, 20, "kOutput"), ("%all-gather-done.1", 20, 60, "")],
+              2: [("%add", 0, 10, "")]}
+    summary = tr.summarize(host_steps([(0, 100), (100, 200)]), device)
+    assert summary["collectives"] == ["%all-gather-done.1", "%all-gather.1"]
+    m = type("M", (), {"trace": summary})()
+    reader = run.load_module("metrics", "exposed_collective_ms")
+    assert reader.read(m) == 40 / 1e6 / 2  # device 1's 40 ns over two steps
+    no_collective = tr.summarize(host_steps([(0, 100)]), {0: [("%add", 0, 10, "")]})
+    assert reader.read(type("M", (), {"trace": no_collective})()) is None
+    assert reader.read(type("M", (), {"trace": None})()) is None
+
+
 def test_summarize_without_steps_is_none():
     assert tr.summarize([], {0: [("%add", 0, 1, "")]}) is None
 

@@ -14,6 +14,12 @@ of the last; device time outside it is cut off.
   XLA output fusions (``kind=kOutput``: a convolution or a dot with its
   epilogue; in this model the dots are the fc layer's, a few tenths of
   a percent of the time);
+- collective time: the summed durations of collective events (ops whose
+  name holds ``all-gather``, ``all-reduce``, ``reduce-scatter``,
+  ``collective-permute`` or ``all-to-all``, with their ``-start`` and
+  ``-done`` halves and fusions), and the exposed part of it: the union
+  of the collective intervals less the union of every other op's, the
+  time a device spent in collectives with nothing else running on it;
 - the breakdown: the device ops that took most time, and the longest
   idle gaps of the first device, each named by the innermost span open
   at the gap's middle on the host thread that ran the steps.
@@ -101,6 +107,13 @@ def is_conv(name: str, kind: str) -> bool:
     return "conv" in name.lower() or kind == "kOutput"
 
 
+COLLECTIVE = re.compile(r"all-gather|all-reduce|reduce-scatter|collective-permute|all-to-all")
+
+
+def is_collective(name: str) -> bool:
+    return COLLECTIVE.search(name.lower()) is not None
+
+
 def summarize(host, device: Dict[int, list], top: int = 10) -> dict:
     """The numbers of one traced window (see the module docstring);
     ``None`` when the trace holds no ``bench.train`` span."""
@@ -109,16 +122,22 @@ def summarize(host, device: Dict[int, list], top: int = 10) -> dict:
         return None
     lo, hi = min(s for s, _ in steps), max(e for _, e in steps)
     window_ns = hi - lo
-    per_device, op_time = {}, collections.Counter()
+    per_device, op_time, collectives = {}, collections.Counter(), set()
     for dev, ops in sorted(device.items()):
         ops = clip(ops, lo, hi)
         spans = [(s, e) for _, s, e, _ in ops]
+        coll = [(s, e) for n, s, e, _ in ops if is_collective(n)]
+        other = [(s, e) for n, s, e, _ in ops if not is_collective(n)]
+        busy = length(spans)
         per_device[dev] = {
-            "busy_ns": length(spans),
+            "busy_ns": busy,
             "conv_ns": sum(e - s for n, s, e, c in ops if is_conv(n, c)),
+            "collective_ns": sum(e - s for s, e in coll),
+            "exposed_collective_ns": busy - length(other),
         }
         for n, s, e, _ in ops:
             op_time[n] += e - s
+        collectives.update(n for n, _, _, _ in ops if is_collective(n))
     gaps = []
     if device:
         first = min(device)
@@ -131,6 +150,7 @@ def summarize(host, device: Dict[int, list], top: int = 10) -> dict:
         "window_ns": window_ns,
         "steps": len(steps),
         "devices": per_device,
+        "collectives": sorted(collectives),
         "device_ops": [[n, t / 1e9] for n, t in op_time.most_common(top)],
         "idle_gaps": [[n, t / 1e9] for n, t in gaps],
     }
